@@ -40,6 +40,7 @@ from benchmarks.common import append_bench_json, emit, time_fn
 from benchmarks.bench_speed import BENCH_JSON
 from repro.core.optim import make_optimizer
 from repro.train import loop as L
+from repro.launch.mesh import make_mesh
 
 SHARDS = 4
 BUCKETS = 4
@@ -65,7 +66,7 @@ def bench_step_overlap(smoke: bool = False):
              f"needs {SHARDS} devices "
              f"(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
         return None
-    mesh = jax.make_mesh((SHARDS,), ("data",))
+    mesh = make_mesh((SHARDS,), ("data",))
     rows, cols, n_leaves = (32, 1024, 12) if smoke else (64, 1024, 12)
     iters = 5 if smoke else 10
 

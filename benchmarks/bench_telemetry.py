@@ -34,6 +34,7 @@ from benchmarks.common import append_bench_json, emit
 from repro import telemetry as tel
 from repro.core.optim import make_optimizer
 from repro.telemetry import tracing
+from repro.launch.mesh import make_mesh
 
 STEPS = 10
 EVERY = 2
@@ -42,7 +43,7 @@ SHARDS = 4
 
 def bench_telemetry_jsonl(smoke: bool = False):
     shards = SHARDS if jax.device_count() >= SHARDS else 1
-    mesh = jax.make_mesh((shards,), ("data",)) if shards > 1 else None
+    mesh = make_mesh((shards,), ("data",)) if shards > 1 else None
     key = jax.random.PRNGKey(0)
     n_mat, n_vec = 2, 6
     params = {f"w{i}": jax.random.normal(jax.random.fold_in(key, i),

@@ -39,6 +39,8 @@ SMOKE_SUITES = ("speed",)
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", type=str, default=None,
                     help="comma-separated suite names")
@@ -95,6 +97,7 @@ def main() -> None:
     if args.serve and "serve" not in names:
         names.append("serve")
     print("name,us_per_call,derived")
+    failed = []
     for n in names:
         mod_name, desc = SUITES[n]
         print(f"# === {n}: {desc} ===")
@@ -113,11 +116,12 @@ def main() -> None:
             kwargs["telemetry"] = True
         try:
             mod.main(**kwargs)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other suites, then fail
             print(f"{n}/ERROR,0,{e!r}", file=sys.stderr)
             print(f"{n}/ERROR,0,{e!r}")
-            if args.smoke:
-                raise SystemExit(1)  # CI must fail loudly
+            failed.append(n)
+    if failed:
+        raise SystemExit(f"failed suites: {','.join(failed)}")
 
 
 if __name__ == "__main__":

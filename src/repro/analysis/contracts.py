@@ -153,13 +153,24 @@ def check_accumulates_in(text: str, dtype: str = "f32",
     return ok, detail
 
 
-_PIN_OPERAND_RE = re.compile(r"\(tensor<(?:(\d+(?:x\d+)*)x)?[a-z0-9]+>\)")
+# Shardy, the partitioner jax 0.9 lowers to: a replicated pin is
+# ``sdy.sharding_constraint %x <@mesh, [{}, {}]> : tensor<...>`` — every
+# dimension's axis list empty.
+_SDY_PIN_RE = re.compile(
+    r"sdy\.sharding_constraint %\S+ <@\w+, \[([^\]]*)\]>"
+    r"(?: : tensor<(?:(\d+(?:x\d+)*)x)?[a-z0-9]+>)?")
+
+# The SPMD boundaries of a shard_map region in Shardy text, in program
+# order: a sharded pin (grads into the owned-span layout), the manual
+# region's entry (reduce-scatter side) and its exit (all-gather side).
+SPMD_BOUNDARY_MARKERS = ("sdy.sharding_constraint", "sdy.manual_computation",
+                         "sdy.return")
 
 
 def replicated_pins(text: str, vectors_only: bool = False,
                     exclude_shapes: tuple = ()) -> int:
     """Number of fully-replicated sharding pins in the lowering — the
-    ``custom_call @Sharding`` sites with ``{replicated}`` placement that
+    ``sdy.sharding_constraint`` sites with every dimension unsharded that
     ``rules.replicate_for_scales`` emits (DESIGN.md §12).
 
     ``vectors_only`` skips scalar pins and ``exclude_shapes`` skips named
@@ -167,17 +178,15 @@ def replicated_pins(text: str, vectors_only: bool = False,
     pinned by the arena layout, not by replicate_for_scales) — so callers
     can count specifically the per-tensor scale pins."""
     n = 0
-    for ln in text.splitlines():
-        if "@Sharding" not in ln or "replicated" not in ln:
+    for m in _SDY_PIN_RE.finditer(text):
+        if m.group(1).replace("{}", "").strip(", "):
+            continue                        # some dimension is sharded
+        dims = tuple(int(d) for d in m.group(2).split("x")) \
+            if m.group(2) else ()
+        if vectors_only and not dims:
             continue
-        if vectors_only or exclude_shapes:
-            m = _PIN_OPERAND_RE.search(ln)
-            dims = (tuple(int(d) for d in m.group(1).split("x"))
-                    if m and m.group(1) else ())
-            if vectors_only and not dims:
-                continue
-            if dims in tuple(exclude_shapes):
-                continue
+        if dims in tuple(exclude_shapes):
+            continue
         n += 1
     return n
 

@@ -90,11 +90,11 @@ class TileBudget:
                 "fits": self.fits(budget)}
 
 
-def _onehot_scratch(tile_elems: int) -> int:
-    """The codebook binary-search / requant one-hot intermediates:
-    (tile_elems, CHUNK) compares materialized per codebook chunk
-    (kernels/common.py lookup/requant)."""
-    return tile_elems * _kc.CHUNK * _F32
+def _lookup_scratch(tile_elems: int) -> int:
+    """The codebook search / lookup intermediates of kernels/common.py:
+    one tile-sized accumulator (i32 codes or f32 values) plus the
+    per-entry compare mask of the select chain."""
+    return tile_elems * (_I32 + _F32)
 
 
 def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
@@ -117,8 +117,8 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
     code1 = rows * w1 * _U8          # (rows, w1) u8
     code2 = rows * w2 * _U8
     one = rows * 1 * _F32            # (rows, 1) f32 / i32
-    const = _kc.CODEBOOK_SIZE * _F32  # (1, 256) f32
-    scal = _fu.N_SCALARS * _F32      # (1, 8) f32
+    const = _kc.CODEBOOK_SIZE * _F32  # (256,) f32 in SMEM
+    scal = _fu.N_SCALARS * _F32      # (N_SCALARS,) f32 in SMEM
 
     streamed_in = 2 * row + code1 + one            # p, g, codes_m, absmax_m
     if two:
@@ -137,12 +137,12 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
 
     # Scratch: per-state unpack (i32) + decode (f32) for sub-byte slots,
     # the update intermediates (~2 row-sized f32 temps), the requant
-    # one-hot per state, and the stochastic uniforms.
+    # search accumulators per state, and the stochastic uniforms.
     tile_elems = rows * bsz
     n_states = 2 if two else 1
     scratch = 2 * row                              # update temps
     scratch += n_states * tile_elems * (_I32 + _F32)   # unpack + decode
-    scratch += n_states * _onehot_scratch(tile_elems)  # requant search
+    scratch += n_states * _lookup_scratch(tile_elems)  # requant search
     if stochastic:
         scratch += n_states * tile_elems * _F32        # counter uniforms
 
@@ -157,7 +157,7 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
 def quantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
                   ) -> TileBudget:
     """One ``quantize_blockwise`` grid step (blockwise_quant.py): in
-    (rows, bsz) f32 + (1, 256) codebook -> (rows, w) u8 + (rows, 1) f32."""
+    (rows, bsz) f32 + (256,) SMEM codebook -> (rows, w) u8 + (rows, 1) f32."""
     bsz = block_size
     row = rows * bsz * _F32
     return TileBudget(
@@ -165,21 +165,21 @@ def quantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
         streamed_in=row,
         streamed_out=rows * bsz * _U8 + rows * _F32,
         invariant=2 * _kc.CODEBOOK_SIZE * _F32,     # codebook + bounds
-        scratch=_onehot_scratch(rows * bsz),
+        scratch=_lookup_scratch(rows * bsz),
         config={"rows": rows, "block_size": bsz})
 
 
 def dequantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
                     ) -> TileBudget:
     """One ``dequantize_blockwise`` grid step (blockwise_dequant.py): in
-    (rows, bsz) u8 + (rows, 1) f32 + (1, 256) codebook -> (rows, bsz)."""
+    (rows, bsz) u8 + (rows, 1) f32 + (256,) SMEM codebook -> (rows, bsz)."""
     bsz = block_size
     return TileBudget(
         kernel="blockwise_dequant",
         streamed_in=rows * bsz * _U8 + rows * _F32,
         streamed_out=rows * bsz * _F32,
         invariant=_kc.CODEBOOK_SIZE * _F32,
-        scratch=_onehot_scratch(rows * bsz),
+        scratch=_lookup_scratch(rows * bsz),
         config={"rows": rows, "block_size": bsz})
 
 

@@ -74,7 +74,8 @@ def _mesh(n: int):
     import jax
     if jax.device_count() < n:
         return None
-    return jax.make_mesh((n,), ("data",))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((n,), ("data",))
 
 
 def _make_opt(cell: Cell, **overrides):

@@ -19,11 +19,11 @@ sharding) treats a state slot as (codes-container, absmax) and dispatches on
 ``isinstance(codes, PackedCodes)``.
 """
 from repro.core.lowbit.format import CodeFormat
-from repro.core.lowbit.packing import (SUPPORTED_BITS, PackedCodes,
-                                       pack_codes, packed_width,
+from repro.core.lowbit.packing import (PACK_LAYOUT, SUPPORTED_BITS,
+                                       PackedCodes, pack_codes, packed_width,
                                        unpack_codes, unwrap_codes)
 
 __all__ = [
-    "CodeFormat", "PackedCodes", "SUPPORTED_BITS", "pack_codes",
+    "CodeFormat", "PACK_LAYOUT", "PackedCodes", "SUPPORTED_BITS", "pack_codes",
     "packed_width", "unpack_codes", "unwrap_codes",
 ]

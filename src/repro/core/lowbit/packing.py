@@ -1,24 +1,29 @@
 """Bit-packing of sub-byte optimizer-state codes into uint8 words.
 
-Layout: codes are written MSB-first into a big-endian bitstream per row —
-code i occupies stream bits ``[i*b, (i+1)*b)`` and byte j holds stream bits
-``[8j, 8j+8)`` with stream bit 8j at the byte's bit 7.  For b = 4 this is
-the familiar two-codes-per-byte nibble layout; for b = 5/6 codes straddle
-byte boundaries, which the bitstream formulation handles uniformly.  A row
-of N codes therefore packs to exactly ``N*b/8`` bytes (N must be a multiple
-of 8/gcd(b,8); every supported quantization block size is a multiple of 8).
+Layout (lane-chunked bitstream): a row of N b-bit codes is cut into
+``k = 8 / gcd(b, 8)`` contiguous lane chunks ``C_0 .. C_{k-1}`` of
+``G = N / k`` codes, and its ``N*b/8`` bytes into ``m = b*k/8`` chunks
+``P_0 .. P_{m-1}`` of ``G`` bytes.  Position ``j`` of every chunk forms one
+group: codes ``C_0[j] .. C_{k-1}[j]`` are written MSB-first into a
+big-endian bitstream of ``k*b`` bits, and byte ``P_i[j]`` holds stream bits
+``[8i, 8i+8)`` with stream bit 8i at the byte's bit 7.  For b = 4 this is
+``P_0 = C_0 << 4 | C_1`` (first half of the row in the high nibbles); for
+b = 5/6 codes straddle byte boundaries, which the bitstream formulation
+handles uniformly.  A row of N codes packs to exactly ``N*b/8`` bytes (N
+must be a multiple of k; every supported quantization block size is).
 
-``pack_codes`` / ``unpack_codes`` are pure jnp — broadcast shifts, masks and
-static reshapes only (no gathers, no host round trips) — so the *same
-functions* run inside the Pallas fused-update kernel (unpack → dequant →
-update → requant → pack in VMEM) and on the XLA reference path.  Packed
-codes parity between ``impl="interpret"`` and ``impl="jnp"`` therefore
-holds by construction, the same contract the 8-bit kernels already follow
-(DESIGN.md §3, §9).
+``pack_codes`` / ``unpack_codes`` are pure jnp on 2-D tiles — static lane
+slices, shifts, masks and a lane concatenation, no gathers and no reshapes
+— so the *same functions* run inside the Pallas fused-update kernel
+(unpack → dequant → update → requant → pack in VMEM) and on the XLA
+reference path.  Packed codes parity between the kernels and
+``impl="jnp"`` therefore holds by construction, the same contract the
+8-bit kernels already follow (DESIGN.md §3, §9).
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +31,10 @@ import jax.numpy as jnp
 from repro.errors import FormatError
 
 SUPPORTED_BITS = (4, 5, 6, 8)
+# Name of the byte layout above.  Serialized packed codes carry it (the
+# checkpoint manifest) so bytes packed under any other layout are refused
+# instead of being unpacked into scrambled codes.
+PACK_LAYOUT = "lane_chunked"
 
 
 def packed_width(n_codes: int, bits: int) -> int:
@@ -39,20 +48,37 @@ def packed_width(n_codes: int, bits: int) -> int:
     return (n_codes * bits) // 8
 
 
+def _overlaps(bits: int):
+    """(code chunk c, byte chunk i, width, code shift, byte shift) for every
+    run of stream bits shared by code chunk c and byte chunk i: the run is
+    ``(C_c >> code_shift) & mask`` in the code and sits at ``<< byte_shift``
+    in the byte."""
+    k = 8 // math.gcd(bits, 8)
+    runs = []
+    for c in range(k):
+        lo_c, hi_c = c * bits, (c + 1) * bits
+        for i in range(lo_c // 8, (hi_c - 1) // 8 + 1):
+            lo, hi = max(lo_c, 8 * i), min(hi_c, 8 * i + 8)
+            runs.append((c, i, hi - lo, hi_c - hi, 8 * i + 8 - hi))
+    return k, runs
+
+
 def pack_codes(codes: jax.Array, bits: int) -> jax.Array:
     """(..., N) integer codes in [0, 2^bits) -> (..., N*bits/8) uint8."""
     if bits == 8:
         return codes.astype(jnp.uint8)
-    *lead, n = codes.shape
+    n = codes.shape[-1]
     w = packed_width(n, bits)
+    k, runs = _overlaps(bits)
+    g = n // k
     c = codes.astype(jnp.int32)
-    # codes -> per-code bit planes, MSB first: (..., N, bits)
-    tsel = jax.lax.broadcasted_iota(jnp.int32, (*lead, n, bits), len(lead) + 1)
-    stream = (c[..., None] >> (bits - 1 - tsel)) & 1
-    # bitstream -> bytes, MSB first: (..., W, 8) -> (..., W)
-    stream = stream.reshape(*lead, w, 8)
-    ksel = jax.lax.broadcasted_iota(jnp.int32, (*lead, w, 8), len(lead) + 1)
-    return jnp.sum(stream << (7 - ksel), axis=-1).astype(jnp.uint8)
+    chunks = [c[..., j * g:(j + 1) * g] for j in range(k)]
+    out = [None] * (w // g)
+    for ci, i, width, cshift, bshift in runs:
+        piece = ((chunks[ci] >> cshift) & ((1 << width) - 1)) << bshift
+        out[i] = piece if out[i] is None else out[i] | piece
+    packed = out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
+    return packed.astype(jnp.uint8)
 
 
 def unpack_codes(packed: jax.Array, bits: int) -> jax.Array:
@@ -62,13 +88,16 @@ def unpack_codes(packed: jax.Array, bits: int) -> jax.Array:
     if bits not in SUPPORTED_BITS:
         raise FormatError(f"bits={bits} unsupported; choose from "
                           f"{SUPPORTED_BITS}")
-    *lead, w = packed.shape
-    n = (w * 8) // bits
+    w = packed.shape[-1]
+    k, runs = _overlaps(bits)
+    g = w * 8 // bits // k
     b = packed.astype(jnp.int32)
-    ksel = jax.lax.broadcasted_iota(jnp.int32, (*lead, w, 8), len(lead) + 1)
-    stream = ((b[..., None] >> (7 - ksel)) & 1).reshape(*lead, n, bits)
-    tsel = jax.lax.broadcasted_iota(jnp.int32, (*lead, n, bits), len(lead) + 1)
-    return jnp.sum(stream << (bits - 1 - tsel), axis=-1)
+    planes = [b[..., i * g:(i + 1) * g] for i in range(w // g)]
+    out = [None] * k
+    for ci, i, width, cshift, bshift in runs:
+        piece = ((planes[i] >> bshift) & ((1 << width) - 1)) << cshift
+        out[ci] = piece if out[ci] is None else out[ci] | piece
+    return out[0] if k == 1 else jnp.concatenate(out, axis=-1)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -18,6 +18,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional, Union
 
+import jax
+
 from repro.core.optim.adafactor import Adafactor, AdafactorConfig
 from repro.core.optim.base import (ALGOS, ArenaPartition, FlatSegment,
                                    Full32Leaf, OptimConfig, Pool32Arena,
@@ -51,6 +53,18 @@ def _from_config(cfg, override_32bit=None, mesh=None):
     return Block8bitOptimizer(cfg, override_32bit=override_32bit, mesh=mesh)
 
 
+def _check_auto_axes(mesh) -> None:
+    auto = jax.sharding.AxisType.Auto
+    bad = {name: t for name, t in zip(mesh.axis_names,
+                                      getattr(mesh, "axis_types", ()))
+           if t != auto}
+    if bad:
+        raise ConfigError(
+            f"mesh axes {bad} are not AxisType.Auto; the optimizer's "
+            f"block-domain reshapes need Auto axes — build the mesh with "
+            f"repro.launch.mesh.make_mesh")
+
+
 def make_optimizer(name_or_config: Union[str, OptimConfig, AdafactorConfig],
                    override_32bit: Optional[Callable[[str], bool]] = None,
                    mesh=None,
@@ -79,7 +93,11 @@ def make_optimizer(name_or_config: Union[str, OptimConfig, AdafactorConfig],
     a combined size > 1 and ``partition_shards`` was left at its
     default, the shard count is derived from the mesh — so partitioning
     turns on automatically on data-parallel meshes, and
-    ``partition=False`` opts out."""
+    ``partition=False`` opts out.  The mesh's axes must be Auto (build it
+    with ``repro.launch.mesh.make_mesh``): under Explicit axes the
+    block-domain reshapes cannot be typed, so such a mesh is refused."""
+    if mesh is not None:
+        _check_auto_axes(mesh)
     if isinstance(name_or_config, (OptimConfig, AdafactorConfig)):
         cfg = name_or_config
         if kwargs:

@@ -331,9 +331,9 @@ class Block8bitOptimizer:
         with per-tensor norms computed inline.  Returns (m', r', p')."""
         cfg = self.cfg
         spec = kfu.ALGO_SPECS[self._ew_algo]
-        s = dict(lr=lr, beta1=cfg.beta1, beta2=cfg.beta2, eps=cfg.eps,
-                 weight_decay=cfg.weight_decay, step=step_f,
-                 tensor_scale=jnp.float32(1.0))
+        s = kfu.update_scalars(lr=lr, beta1=cfg.beta1, beta2=cfg.beta2,
+                               eps=cfg.eps, weight_decay=cfg.weight_decay,
+                               step=step_f)
         s["tensor_scale"] = kfu.tensor_scale_for(spec, g, p, m, r, s,
                                                  cfg.trust_coeff)
         return kfu.update_math(spec, g, p, m, r, s)
@@ -622,9 +622,9 @@ class Block8bitOptimizer:
         scales are finalized globally first, so results are unchanged."""
         cfg = self.cfg
         spec = kfu.ALGO_SPECS[self._ew_algo]
-        s = dict(lr=lr, beta1=cfg.beta1, beta2=cfg.beta2, eps=cfg.eps,
-                 weight_decay=cfg.weight_decay, step=step_f,
-                 tensor_scale=jnp.float32(1.0))
+        s = kfu.update_scalars(lr=lr, beta1=cfg.beta1, beta2=cfg.beta2,
+                               eps=cfg.eps, weight_decay=cfg.weight_decay,
+                               step=step_f)
         if spec.needs_norms:
             def seg_scale(i, off, n):
                 shape = pool.segments[i].shape

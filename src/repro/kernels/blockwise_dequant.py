@@ -1,7 +1,7 @@
 """Pallas TPU kernel: block-wise 8-bit dequantization.
 
-Codebook lookup is a chunked one-hot contraction (MXU) — the TPU analogue of
-the CUDA shared-memory LUT gather (DESIGN.md §3).
+Codebook lookup is an exact select chain over an SMEM-resident codebook —
+the TPU analogue of the CUDA shared-memory LUT gather (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -9,7 +9,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import common
@@ -19,7 +18,7 @@ DEFAULT_ROWS = common.DEFAULT_ROWS
 
 def _dequant_kernel(codes_ref, absmax_ref, qmap_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)        # (ROWS, B)
-    vals = common.decode(codes, qmap_ref[...])      # f32
+    vals = common.decode(codes, qmap_ref)      # f32
     out_ref[...] = (vals * absmax_ref[...]).astype(out_ref.dtype)
 
 
@@ -30,10 +29,12 @@ def dequantize_blockwise(
     codebook: jax.Array,
     *,
     rows: int = DEFAULT_ROWS,
-    interpret: bool = True,
+    interpret: bool,
     dtype=jnp.float32,
 ) -> jax.Array:
-    """(codes (n_blocks, B), absmax (n_blocks,)) -> values (n_blocks, B)."""
+    """(codes (n_blocks, B), absmax (n_blocks,)) -> values (n_blocks, B).
+    ``interpret`` has no default: callers say whether they want the chip
+    kernel."""
     n_blocks, bsz = codes.shape
     assert n_blocks % rows == 0, (n_blocks, rows)
     qmap = common.padded_qmap(codebook)
@@ -44,7 +45,7 @@ def dequantize_blockwise(
         in_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
             pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, common.CODEBOOK_SIZE), lambda i: (0, 0)),
+            common.smem_spec(),
         ],
         out_specs=pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, bsz), dtype),

@@ -12,7 +12,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import common
@@ -22,7 +21,7 @@ DEFAULT_ROWS = common.DEFAULT_ROWS  # quantization blocks per grid step
 
 def _quant_kernel(x_ref, bounds_ref, codes_ref, absmax_ref):
     x = x_ref[...].astype(jnp.float32)              # (ROWS, B)
-    codes, absmax = common.block_requantize(x, bounds_ref[...])
+    codes, absmax = common.block_requantize(x, bounds_ref)
     codes_ref[...] = codes.astype(jnp.uint8)
     absmax_ref[...] = absmax                        # (ROWS, 1)
 
@@ -33,11 +32,12 @@ def quantize_blockwise(
     codebook: jax.Array,
     *,
     rows: int = DEFAULT_ROWS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(n_blocks, B) -> (codes uint8 (n_blocks, B), absmax f32 (n_blocks,)).
 
-    n_blocks must be a multiple of ``rows`` (ops.py pads).
+    n_blocks must be a multiple of ``rows`` (ops.py pads).  ``interpret``
+    has no default: callers say whether they want the chip kernel.
     """
     n_blocks, bsz = x.shape
     assert n_blocks % rows == 0, (n_blocks, rows)
@@ -48,7 +48,7 @@ def quantize_blockwise(
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((1, common.CODEBOOK_SIZE), lambda i: (0, 0)),
+            common.smem_spec(),
         ],
         out_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),

@@ -2,38 +2,46 @@
 
 TPU adaptation notes (DESIGN.md §3): the CUDA kernels of the paper use
 per-thread binary search + shared-memory LUTs.  On TPU we use gather-free
-formulations:
+formulations that keep every tile 2-D (Mosaic lowers no 2-D -> 1-D shape
+cast and no value-level ``dynamic_slice``):
 
-  * nearest-code search: ``code = sum_j [x >= b_j]`` over the 255 midpoint
-    boundaries — broadcast compare + integer sum on the VPU, chunked over the
-    codebook axis so the materialized compare tile stays small in VMEM.
-  * codebook lookup: chunked one-hot contraction ``one_hot(code) @ qmap`` —
-    the MXU-friendly analogue of an SRAM LUT.
+  * nearest-code search: ``code = sum_j [x >= b_j]`` over the midpoint
+    boundaries — one scalar-broadcast compare + integer add per boundary
+    on the VPU, looped over the codebook.
+  * codebook lookup: ``where(code == j, q_j, acc)`` per codebook entry —
+    an exact select chain (no MXU pass, so no reduced-precision matmul can
+    round the looked-up value).
 
-Codebook/boundary inputs are padded to 256 lanes (boundary 256 = +inf) so the
-last dim is hardware-aligned.
+Codebooks and boundaries are padded to 256 entries (boundary 256 = +inf)
+and travel as 1-D ``(256,)`` f32 vectors; the kernels stage them in SMEM
+(``smem_spec``) so ``b_j`` / ``q_j`` are scalar loads.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CODEBOOK_SIZE = 256
-# Codebook-axis chunk: bounds the (tile_elems, CHUNK) compare/one-hot
-# materialization in VMEM.
-CHUNK = 64
 # Quantization blocks per grid step, shared by every kernel in this package
 # (DESIGN.md §3: one value so the fused-update and quant/dequant kernels tile
-# the flat block domain identically).
-DEFAULT_ROWS = 8
+# the flat block domain identically).  32 rows is the TPU's native sublane
+# tiling of 8-bit arrays, so uint8 code tiles (packed or not) are
+# tile-aligned.
+DEFAULT_ROWS = 32
 # Seed offsets decorrelating the two state tensors' stochastic rounding.
 STATE1_SEED_SALT = 0
 STATE2_SEED_SALT = 0x9E3779B9
 
 
+def smem_spec() -> pl.BlockSpec:
+    """Whole-array SMEM operand: codebooks, boundaries, scalar vectors."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def padded_bounds(codebook) -> jax.Array:
-    """Midpoint decision boundaries padded with +inf to 256 lanes, (1, 256).
+    """Midpoint decision boundaries padded with +inf to 256 entries, (256,).
 
     For an L-entry codebook (L = 2^bits ≤ 256) the L-1 real boundaries are
     followed by +inf padding, so ``encode`` can only emit codes < L — the
@@ -41,63 +49,48 @@ def padded_bounds(codebook) -> jax.Array:
     cb = jnp.asarray(codebook, dtype=jnp.float32)
     b = (cb[1:] + cb[:-1]) * 0.5
     pad = CODEBOOK_SIZE - b.shape[0]
-    b = jnp.concatenate([b, jnp.full((pad,), jnp.inf, jnp.float32)])
-    return b.reshape(1, CODEBOOK_SIZE)
+    return jnp.concatenate([b, jnp.full((pad,), jnp.inf, jnp.float32)])
 
 
 def padded_qmap(codebook) -> jax.Array:
-    """Codebook zero-padded to 256 lanes, (1, 256) f32.  Padding entries are
+    """Codebook zero-padded to 256 entries, (256,) f32.  Padding entries are
     unreachable: codes from ``encode``/``block_requantize`` stay below the
     real level count."""
     cb = jnp.asarray(codebook, dtype=jnp.float32)
     pad = CODEBOOK_SIZE - cb.shape[0]
     if pad:
         cb = jnp.concatenate([cb, jnp.zeros((pad,), jnp.float32)])
-    return cb.reshape(1, CODEBOOK_SIZE)
+    return cb
 
 
-def _n_chunks(n_levels: int) -> int:
-    """Codebook chunks that can contain live lanes for an n_levels map."""
-    return -(-min(n_levels, CODEBOOK_SIZE) // CHUNK)
-
-
-def encode(x_norm: jax.Array, bounds_row: jax.Array,
-           n_levels: int = CODEBOOK_SIZE) -> jax.Array:
+def encode(x_norm: jax.Array, bounds, n_levels: int = CODEBOOK_SIZE
+           ) -> jax.Array:
     """Nearest-code indices for normalized values in [-1, 1].
 
-    x_norm: (..., N) f32; bounds_row: (1, 256) f32 (+inf beyond the real
-    boundaries).  Returns int32 codes. ``sum_j [x >= b_j]`` ==
-    searchsorted(side='right').  ``n_levels`` (2^bits for k-bit maps)
-    bounds the chunk sweep: lanes past it are +inf and contribute nothing,
-    so sub-byte codebooks skip ~3/4 of the compare work.
+    x_norm: (..., N) f32; bounds: (256,) f32 boundaries (an SMEM ref inside
+    a kernel, an array outside).  Returns int32 codes.
+    ``sum_j [x >= b_j]`` == searchsorted(side='right').  ``n_levels``
+    (2^bits for k-bit maps) bounds the sweep to the real boundaries, so
+    sub-byte codebooks skip ~15/16 of the compare work.
     """
-    flat = x_norm.reshape(-1)
-    acc = jnp.zeros(flat.shape, dtype=jnp.int32)
-    for c in range(0, _n_chunks(n_levels) * CHUNK, CHUNK):
-        chunk = jax.lax.dynamic_slice(bounds_row, (0, c), (1, CHUNK))  # (1, CHUNK)
-        acc = acc + jnp.sum(
-            (flat[:, None] >= chunk).astype(jnp.int32), axis=-1
-        )
-    return acc.reshape(x_norm.shape)
+    def body(j, acc):
+        return acc + (x_norm >= bounds[j]).astype(jnp.int32)
+    return jax.lax.fori_loop(0, min(n_levels, CODEBOOK_SIZE) - 1, body,
+                             jnp.zeros(x_norm.shape, jnp.int32))
 
 
-def decode(codes: jax.Array, qmap_row: jax.Array,
-           n_levels: int = CODEBOOK_SIZE) -> jax.Array:
-    """Codebook lookup via chunked one-hot contraction (MXU-friendly).
+def decode(codes: jax.Array, qmap, n_levels: int = CODEBOOK_SIZE
+           ) -> jax.Array:
+    """Codebook lookup as an exact select chain.
 
-    codes: (..., N) int32 in [0, n_levels); qmap_row: (1, 256) f32.
-    ``n_levels`` bounds the chunk sweep (codes never reach padded lanes).
+    codes: (..., N) int32 in [0, n_levels); qmap: (256,) f32 (SMEM ref
+    inside a kernel, array outside).  ``n_levels`` bounds the sweep
+    (codes never reach padded entries).
     """
-    flat = codes.reshape(-1)
-    acc = jnp.zeros(flat.shape, dtype=jnp.float32)
-    for c in range(0, _n_chunks(n_levels) * CHUNK, CHUNK):
-        chunk = jax.lax.dynamic_slice(qmap_row, (0, c), (1, CHUNK))[0]  # (CHUNK,)
-        onehot = (flat[:, None] == (c + jax.lax.iota(jnp.int32, CHUNK))[None, :])
-        acc = acc + jax.lax.dot(
-            onehot.astype(jnp.float32), chunk[:, None],
-            preferred_element_type=jnp.float32,
-        )[:, 0]
-    return acc.reshape(codes.shape)
+    def body(j, acc):
+        return jnp.where(codes == j, qmap[j], acc)
+    return jax.lax.fori_loop(0, min(n_levels, CODEBOOK_SIZE), body,
+                             jnp.zeros(codes.shape, jnp.float32))
 
 
 def hash_uniform(idx: jax.Array, seed: jax.Array) -> jax.Array:
@@ -116,7 +109,9 @@ def hash_uniform(idx: jax.Array, seed: jax.Array) -> jax.Array:
     x = x * jnp.uint32(0x735A2D97)
     x = x ^ (x >> 15)
     # Top-of-24-bits mantissa -> exactly representable uniform in [0, 1).
-    return (x >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # Via int32 (exact below 2^24): Mosaic has no uint32 -> f32 cast.
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def element_indices(n_rows: int, n_cols: int, row_offset) -> jax.Array:
@@ -142,8 +137,7 @@ def stochastic_codes(x_norm: jax.Array, codes: jax.Array, q_near: jax.Array,
     return jnp.where(u < p_other, other, codes)
 
 
-def block_requantize(x: jax.Array, bounds_row: jax.Array,
-                     qmap_row: jax.Array | None = None,
+def block_requantize(x: jax.Array, bounds, qmap=None,
                      random_u: jax.Array | None = None,
                      max_code: int = CODEBOOK_SIZE - 1
                      ) -> tuple[jax.Array, jax.Array]:
@@ -152,7 +146,7 @@ def block_requantize(x: jax.Array, bounds_row: jax.Array,
 
     With ``random_u`` (uniforms in [0, 1), same shape as x) the encode is
     stochastic: round to the nearer/farther neighbouring code with
-    probability proportional to proximity (paper App H). ``qmap_row`` is
+    probability proportional to proximity (paper App H). ``qmap`` is
     required in that case for the neighbour lookups.  ``max_code`` is the
     highest valid code (2^bits - 1 for k-bit codebooks); deterministic
     encode respects it by construction via the +inf boundary padding."""
@@ -160,11 +154,11 @@ def block_requantize(x: jax.Array, bounds_row: jax.Array,
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax, 1.0)
     x_norm = x / scale
-    codes = encode(x_norm, bounds_row, n_levels)
+    codes = encode(x_norm, bounds, n_levels)
     if random_u is not None:
-        q_near = decode(codes, qmap_row, n_levels)
+        q_near = decode(codes, qmap, n_levels)
         direction = jnp.where(x_norm > q_near, 1, -1)
         other = jnp.clip(codes + direction, 0, max_code)
-        q_other = decode(other, qmap_row, n_levels)
+        q_other = decode(other, qmap, n_levels)
         codes = stochastic_codes(x_norm, codes, q_near, q_other, other, random_u)
     return codes, absmax

@@ -59,16 +59,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.lowbit import pack_codes, packed_width, unpack_codes
-from repro.errors import FormatError
 from repro.kernels import common
 
-# scalar vector layout:
-# [lr, beta1, beta2, eps, weight_decay, step, gnorm_scale, tensor_scale]
-# Slot 7 holds trust_coeff on entry to fused_update_pallas; norm-needing
-# algorithms (lamb/lars) consume the finalized per-block tensor_scale via a
-# dedicated (n_blocks, 1) input instead, and the slot is rewritten to 1.0
-# before the main kernel runs.
-N_SCALARS = 8
+# Scalar vector layout (an SMEM operand of every kernel; ``scalars_vector``
+# builds it).  Slot 7 holds trust_coeff on entry to fused_update_pallas;
+# norm-needing algorithms (lamb/lars) consume the finalized per-block
+# tensor_scale via a dedicated (n_blocks, 1) input instead, and the slot is
+# rewritten to 1.0 before the main kernel runs.  c1/c2 are the adam bias
+# corrections (``update_scalars``), precomputed outside the kernel
+# because Mosaic has no scalar ``pow``.
+SCALAR_SLOTS = ("lr", "beta1", "beta2", "eps", "weight_decay", "step",
+                "gnorm_scale", "tensor_scale", "c1", "c2")
+N_SCALARS = len(SCALAR_SLOTS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,7 +117,7 @@ class FusedUpdateResult(NamedTuple):
     """Output of one fused update in the flat block domain.
 
     ``health`` is the optional numerics-sentinel output (DESIGN.md §16):
-    per-block f32 counts ``(n_blocks, N_SCALARS)`` in :data:`HEALTH_SLOTS`
+    per-block f32 counts ``(n_blocks, N_HEALTH)`` in :data:`HEALTH_SLOTS`
     order, present iff the dispatch ran with ``sentinel=True``.  A ``None``
     leaf vanishes in pytree flattening, so sentinel-off results (and their
     lowerings) are unchanged by the field's existence."""
@@ -143,8 +145,8 @@ HEALTH_SLOTS = (
     "absmax_overflow_r",     # new state-2 absmax past the overflow guard
 )
 N_HEALTH = len(HEALTH_SLOTS)
-if N_HEALTH != N_SCALARS:  # health rows reuse the (rows, 8) tile shape
-    raise FormatError("HEALTH_SLOTS must match the N_SCALARS tile width")
+# Norm-prologue partials share the (rows, N_HEALTH) tile shape.
+N_PARTIALS = N_HEALTH
 
 # f32 max is ~3.4e38; an absmax past 1e30 means squaring/scale math on the
 # dequantized state is about to overflow — flag before the inf appears.
@@ -202,13 +204,27 @@ def adam_moments(g, m, r, s):
     return m2, r2
 
 
+def update_scalars(*, lr, beta1, beta2, eps, weight_decay, step,
+                   tensor_scale=None) -> dict:
+    """The ``s`` dict of ``update_math``, with adam's bias corrections
+    ``c1 = 1 - beta1^step`` and ``c2 = 1 - beta2^step``.  They are computed
+    here, by XLA, for every path — Mosaic lowers no scalar ``pow``; the
+    kernels read them from ``scalars_vector`` — so all paths use the same
+    c1/c2.  Values keep the types they are given."""
+    return dict(lr=lr, beta1=beta1, beta2=beta2, eps=eps,
+                weight_decay=weight_decay, step=step,
+                tensor_scale=(jnp.float32(1.0) if tensor_scale is None
+                              else tensor_scale),
+                c1=1.0 - jnp.power(beta1, step),
+                c2=1.0 - jnp.power(beta2, step))
+
+
 def adam_base_update(g, p, m, r, s):
     """Bias-corrected adam step direction incl. decoupled weight decay —
     the pre-trust-ratio 'u' of LAMB. Returns (m2, r2, u)."""
     m2, r2 = adam_moments(g, m, r, s)
-    c1 = 1.0 - jnp.power(s["beta1"], s["step"])
-    c2 = 1.0 - jnp.power(s["beta2"], s["step"])
-    u = (m2 / c1) / (jnp.sqrt(r2 / c2) + s["eps"]) + s["weight_decay"] * p
+    u = ((m2 / s["c1"]) / (jnp.sqrt(r2 / s["c2"]) + s["eps"])
+         + s["weight_decay"] * p)
     return m2, r2, u
 
 
@@ -296,11 +312,21 @@ def tensor_scale_for(spec: AlgoSpec, g, p, m, r, s, trust_coeff):
                                    trust_coeff=trust_coeff)
 
 
-def _scalars_dict(scal_row):
-    return dict(lr=scal_row[0, 0], beta1=scal_row[0, 1], beta2=scal_row[0, 2],
-                eps=scal_row[0, 3], weight_decay=scal_row[0, 4],
-                step=scal_row[0, 5], gnorm_scale=scal_row[0, 6],
-                tensor_scale=scal_row[0, 7])
+def scalars_vector(lr, beta1, beta2, eps, weight_decay, step, gnorm_scale,
+                   trust_coeff) -> jax.Array:
+    """The (N_SCALARS,) f32 kernel scalar vector in SCALAR_SLOTS order,
+    bias corrections included."""
+    vals = [jnp.asarray(x, jnp.float32)
+            for x in (lr, beta1, beta2, eps, weight_decay, step,
+                      gnorm_scale, trust_coeff)]
+    s = update_scalars(lr=vals[0], beta1=vals[1], beta2=vals[2],
+                       eps=vals[3], weight_decay=vals[4], step=vals[5])
+    return jnp.stack(vals + [s["c1"], s["c2"]])
+
+
+def _scalars_dict(scal_ref):
+    """SMEM scalar vector -> the ``s`` dict of ``update_math``."""
+    return {k: scal_ref[i] for i, k in enumerate(SCALAR_SLOTS)}
 
 
 # ------------------------------------------------------------ kernel builder
@@ -328,7 +354,7 @@ def _make_update_kernel(spec: AlgoSpec, rows: int, bsz: int, stochastic: bool,
         c2_out, a2_out = (next(it), next(it)) if two else (None, None)
         h_out = next(it) if sentinel else None
 
-        s = _scalars_dict(scal_ref[...])
+        s = _scalars_dict(scal_ref)
         if spec.needs_norms:
             # Per-block trust ratio / local lr from the norm prologue;
             # constant within a segment, broadcast over the block dim.
@@ -336,11 +362,11 @@ def _make_update_kernel(spec: AlgoSpec, rows: int, bsz: int, stochastic: bool,
         g = g_ref[...].astype(jnp.float32) * s["gnorm_scale"]
         p = p_ref[...].astype(jnp.float32)
 
-        # ---- unpack sub-byte codes + dequantize (one-hot on MXU) ----
+        # ---- unpack sub-byte codes + dequantize (SMEM codebook) ----
         m = common.decode(unpack_codes(c1_ref[...], bits_m),
-                          qm1_ref[...], 1 << bits_m) * a1_ref[...]
+                          qm1_ref, 1 << bits_m) * a1_ref[...]
         r = (common.decode(unpack_codes(c2_ref[...], bits_r),
-                           qm2_ref[...], 1 << bits_r) * a2_ref[...]
+                           qm2_ref, 1 << bits_r) * a2_ref[...]
              if two else None)
 
         # ---- 32-bit update math in registers ----
@@ -360,14 +386,14 @@ def _make_update_kernel(spec: AlgoSpec, rows: int, bsz: int, stochastic: bool,
             u1 = common.hash_uniform(idx, seed + jnp.uint32(common.STATE1_SEED_SALT))
             if two:
                 u2 = common.hash_uniform(idx, seed + jnp.uint32(common.STATE2_SEED_SALT))
-        c1n, a1n = common.block_requantize(m2, b1_ref[...], qm1_ref[...],
+        c1n, a1n = common.block_requantize(m2, b1_ref, qm1_ref,
                                            random_u=u1,
                                            max_code=(1 << bits_m) - 1)
         c1_out[...] = pack_codes(c1n, bits_m)
         a1_out[...] = a1n
         c2n = a2n = None
         if two:
-            c2n, a2n = common.block_requantize(r2, b2_ref[...], qm2_ref[...],
+            c2n, a2n = common.block_requantize(r2, b2_ref, qm2_ref,
                                                random_u=u2,
                                                max_code=(1 << bits_r) - 1)
             c2_out[...] = pack_codes(c2n, bits_r)
@@ -384,8 +410,8 @@ def _make_update_kernel(spec: AlgoSpec, rows: int, bsz: int, stochastic: bool,
 
 def _make_norm_kernel(spec: AlgoSpec, rows: int, bsz: int,
                       bits_m: int, bits_r: int):
-    """Norm prologue: per-**block** partial squared norms, one (rows, 8)
-    tile of rows [||p||^2, ||g||^2, ||u||^2, 0...] per grid step.  Block
+    """Norm prologue: per-**block** partial squared norms, one
+    (rows, N_PARTIALS) tile of rows [||p||^2, ||g||^2, ||u||^2, 0...] per grid step.  Block
     granularity (not grid-row granularity) is what lets the XLA side
     finalize the partials per *segment* under the pooled dispatch, where a
     leaf boundary need not be tile-aligned.  lars only needs p and g; lamb
@@ -403,7 +429,7 @@ def _make_norm_kernel(spec: AlgoSpec, rows: int, bsz: int,
             p_ref, g_ref = next(it), next(it)
         out_ref = next(it)
 
-        s = _scalars_dict(scal_ref[...])
+        s = _scalars_dict(scal_ref)
         g = g_ref[...].astype(jnp.float32) * s["gnorm_scale"]
         p = p_ref[...].astype(jnp.float32)
         pn2 = jnp.sum(p * p, axis=1)                      # (rows,)
@@ -411,9 +437,9 @@ def _make_norm_kernel(spec: AlgoSpec, rows: int, bsz: int,
         un2 = jnp.zeros((rows,), jnp.float32)
         if spec.norm_kind == "lamb":
             m = common.decode(unpack_codes(c1_ref[...], bits_m),
-                              qm1_ref[...], 1 << bits_m) * a1_ref[...]
+                              qm1_ref, 1 << bits_m) * a1_ref[...]
             r = common.decode(unpack_codes(c2_ref[...], bits_r),
-                              qm2_ref[...], 1 << bits_r) * a2_ref[...]
+                              qm2_ref, 1 << bits_r) * a2_ref[...]
             _, _, u = adam_base_update(g, p, m, r, s)
             un2 = jnp.sum(u * u, axis=1)
         zero = jnp.zeros((rows,), jnp.float32)
@@ -427,7 +453,7 @@ def _norm_partials_pallas(spec: AlgoSpec, p, g, codes_m, absmax_m, codes_r,
                           absmax_r, qm1, qm2, scalars, *, rows: int,
                           bits_m: int, bits_r: int, interpret: bool):
     """Run the norm prologue over the whole input: per-block partial
-    squared norms, (n_blocks, N_SCALARS) f32.  Shared by the fused update
+    squared norms, (n_blocks, N_PARTIALS) f32.  Shared by the fused update
     and the standalone segment-scale pass of the partitioned dispatch
     (DESIGN.md §12) — one implementation, so both produce bit-identical
     partials."""
@@ -436,12 +462,11 @@ def _norm_partials_pallas(spec: AlgoSpec, p, g, codes_m, absmax_m, codes_r,
     row_spec = pl.BlockSpec((rows, bsz), lambda i: (i, 0))
     code1_spec = pl.BlockSpec((rows, w1), lambda i: (i, 0))
     one_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
-    const_spec = pl.BlockSpec((1, common.CODEBOOK_SIZE), lambda i: (0, 0))
-    scal_spec = pl.BlockSpec((1, N_SCALARS), lambda i: (0, 0))
+    const_spec = scal_spec = common.smem_spec()
 
     norm_kernel = _make_norm_kernel(spec, rows, bsz, bits_m, bits_r)
     in_specs = [scal_spec]
-    args = [scalars.reshape(1, N_SCALARS)]
+    args = [scalars]
     if spec.norm_kind == "lamb":
         in_specs += [const_spec, const_spec]
         args += [qm1, qm2]
@@ -456,8 +481,8 @@ def _norm_partials_pallas(spec: AlgoSpec, p, g, codes_m, absmax_m, codes_r,
         norm_kernel,
         grid=(n_blocks // rows,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((rows, N_SCALARS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, N_SCALARS), jnp.float32),
+        out_specs=pl.BlockSpec((rows, N_PARTIALS), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, N_PARTIALS), jnp.float32),
         interpret=interpret,
     )(*args)
 
@@ -482,8 +507,8 @@ def segment_scales_from_partials(spec: AlgoSpec, partials, segments,
                                              "segments"))
 def segment_scales_pallas(
     p, g, codes_m, absmax_m, codes_r, absmax_r, qmap_m, qmap_r, scalars,
-    *, algo: str, rows: int = common.DEFAULT_ROWS, stochastic: bool = False,
-    interpret: bool = True, bits_m: int = 8, bits_r: int = 8,
+    *, algo: str, interpret: bool, rows: int = common.DEFAULT_ROWS,
+    stochastic: bool = False, bits_m: int = 8, bits_r: int = 8,
     segments: tuple = (),
 ) -> jax.Array:
     """Standalone norm-prologue pass -> (n_blocks,) per-block tensor_scale,
@@ -523,15 +548,15 @@ def fused_update_pallas(
     absmax_r: Optional[jax.Array],
     qmap_m: jax.Array,             # (2^bits_m,) state-1 codebook
     qmap_r: Optional[jax.Array],   # (2^bits_r,) state-2 codebook
-    scalars: jax.Array,            # (N_SCALARS,) f32 (tensor_scale slot unused)
+    scalars: jax.Array,            # (N_SCALARS,) f32, ``scalars_vector``
     block_seeds: jax.Array,        # (n_blocks,) int32 per-block rounding seeds
     block_offsets: jax.Array,      # (n_blocks,) int32 leaf-local block index
     tensor_scale_blocks: Optional[jax.Array] = None,  # (n_blocks,) f32
     *,
     algo: str,
+    interpret: bool,               # no default: say whether it is the chip
     rows: int = common.DEFAULT_ROWS,
     stochastic: bool = False,
-    interpret: bool = True,
     bits_m: int = 8,
     bits_r: int = 8,
     segments: tuple = (),          # ((block_offset, n_blocks), ...) static
@@ -540,8 +565,8 @@ def fused_update_pallas(
     """One fused k-bit update for ``algo`` in the flat block domain.
 
     ``n_blocks`` must be a multiple of ``rows`` (ops.fused_update pads).
-    ``scalars`` layout: [lr, beta1, beta2, eps, weight_decay, step,
-    gnorm_scale, trust_coeff].  ``block_seeds`` / ``block_offsets`` give
+    ``scalars`` layout: SCALAR_SLOTS (``scalars_vector`` builds it; slot 7
+    holds trust_coeff).  ``block_seeds`` / ``block_offsets`` give
     every block its stochastic-rounding seed and its block index *within
     its own leaf* — a constant seed plus ``arange`` offsets reproduce the
     single-tensor behaviour; the pooled dispatch (DESIGN.md §10) passes one
@@ -574,8 +599,7 @@ def fused_update_pallas(
     code1_spec = pl.BlockSpec((rows, w1), lambda i: (i, 0))
     code2_spec = pl.BlockSpec((rows, w2), lambda i: (i, 0)) if two else None
     one_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
-    const_spec = pl.BlockSpec((1, common.CODEBOOK_SIZE), lambda i: (0, 0))
-    scal_spec = pl.BlockSpec((1, N_SCALARS), lambda i: (0, 0))
+    const_spec = scal_spec = common.smem_spec()
 
     qm1, b1 = common.padded_qmap(qmap_m), common.padded_bounds(qmap_m)
     if two:
@@ -603,7 +627,7 @@ def fused_update_pallas(
     kernel = _make_update_kernel(spec, rows, bsz, stochastic, bits_m, bits_r,
                                  sentinel)
     in_specs = [scal_spec]
-    args = [scalars.reshape(1, N_SCALARS)]
+    args = [scalars]
     if stochastic:
         in_specs += [one_spec, one_spec]
         args += [block_seeds.astype(jnp.int32)[:, None],

@@ -141,23 +141,14 @@ def registered(algo: str | None = None) -> list[tuple[str, str]]:
     return sorted(k for k in _REGISTRY if algo is None or k[0] == algo)
 
 
-def _scalars_vec(lr, beta1, beta2, eps, weight_decay, step, gnorm_scale,
-                 trust_coeff) -> jax.Array:
-    """The (N_SCALARS,) f32 hyperparameter vector in the kernel's fixed
-    slot order (fused_update.N_SCALARS layout)."""
-    return jnp.stack([jnp.asarray(x, jnp.float32)
-                      for x in (lr, beta1, beta2, eps, weight_decay, step,
-                                gnorm_scale, trust_coeff)])
-
-
 def _pallas_entry(algo: str, interpret: bool) -> Callable:
     def run(p, g, cm, am, cr, ar, qmap_m, qmap_r, *,
             lr, beta1, beta2, eps, weight_decay, step, trust_coeff,
             gnorm_scale, stochastic, seed, rows, bits_m=8, bits_r=8,
             block_seeds=None, block_offsets=None, segments=None,
             tensor_scale_blocks=None, sentinel=False):
-        scalars = _scalars_vec(lr, beta1, beta2, eps, weight_decay, step,
-                               gnorm_scale, trust_coeff)
+        scalars = _fu.scalars_vector(lr, beta1, beta2, eps, weight_decay,
+                                     step, gnorm_scale, trust_coeff)
         two = _fu.ALGO_SPECS[algo].n_states == 2
         nb = p.shape[0]
         # Single-tensor defaults: one segment, a shared seed, arange block
@@ -443,8 +434,8 @@ def segment_tensor_scales(
         return ref.segment_scales_ref(p, g, cm, absmax_m, cr, absmax_r,
                                       qmap_m, qmap_r, algo=algo,
                                       segments=segments, **hyper)
-    scalars = _scalars_vec(lr, beta1, beta2, eps, weight_decay, step,
-                           gnorm_scale, trust_coeff)
+    scalars = _fu.scalars_vector(lr, beta1, beta2, eps, weight_decay, step,
+                                 gnorm_scale, trust_coeff)
     two = spec.n_states == 2
     arrs = [p, g, codes_m, absmax_m] + ([codes_r, absmax_r] if two else [])
     arrs, _ = _pad_rows(arrs, nb, rows)

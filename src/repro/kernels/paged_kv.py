@@ -24,8 +24,8 @@ Two data paths, both independent of the page *allocator* (host-side, in
     slot's page table are gathered and decoded to (B, L, KV, Dh) values.
     ``impl="pallas"`` is the TPU kernel: the page table rides scalar
     prefetch (``PrefetchScalarGridSpec``) so each grid step DMAs exactly
-    one physical page HBM->VMEM, and the codebook lookup is the chunked
-    one-hot contraction every kernel in this package uses (common.decode).
+    one physical page HBM->VMEM, and the codebook lookup is the SMEM
+    select chain every kernel in this package uses (common.decode).
     ``impl="jnp"`` is the XLA oracle; parity is exercised in
     tests/test_serve_paged.py.
 """
@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import qmap as qmap_lib
 from repro.core.lowbit import pack_codes, unpack_codes
@@ -100,10 +101,20 @@ def quantize_rows(x: jax.Array, bits: int = 8
 
 def dequantize_rows(codes: jax.Array, absmax: jax.Array, dtype,
                     bits: int = 8) -> jax.Array:
-    """(codes (..., W), absmax (...,)) -> values (..., Dh) in ``dtype``."""
+    """(codes (..., W), absmax (...,)) -> values (..., Dh) in ``dtype``.
+
+    A narrower float ``dtype`` is rounded explicitly (``reduce_precision``)
+    before the cast: XLA may drop a cast pair such as f32->bf16->f32 under
+    excess precision, while the Pallas gather writes real ``dtype`` values
+    to memory, and the two impls must agree exactly."""
     cb = kv_qmap(bits)
     idx = unpack_codes(codes, bits) if bits != 8 else codes.astype(jnp.int32)
-    return (cb[idx] * absmax[..., None]).astype(dtype)
+    vals = cb[idx] * absmax[..., None]
+    fi = jnp.finfo(dtype)
+    if fi.bits < 32:
+        vals = jax.lax.reduce_precision(vals, exponent_bits=fi.nexp,
+                                        mantissa_bits=fi.nmant)
+    return vals.astype(dtype)
 
 
 # ----------------------------------------------------------------- append
@@ -131,18 +142,18 @@ def _gather_kernel(table_ref, codes_ref, absmax_ref, qmap_ref, out_ref,
                    *, bits: int):
     """One grid step = one (slot, logical page) cell: the physical page
     selected by the scalar-prefetched table is already in VMEM (index_map
-    DMA); unpack -> one-hot decode -> scale."""
+    DMA); unpack -> select-chain decode -> scale."""
     del table_ref  # consumed by the index maps
-    codes = codes_ref[...]                       # (1, page, KV, W) uint8
+    codes = codes_ref[0]                         # (page, KV, W) uint8
     if bits != 8:
-        codes = unpack_codes(codes, bits)        # (1, page, KV, Dh)
-    vals = common.decode(codes.astype(jnp.int32), qmap_ref[...],
+        codes = unpack_codes(codes, bits)        # (page, KV, Dh)
+    vals = common.decode(codes.astype(jnp.int32), qmap_ref,
                          n_levels=2 ** bits)
-    out_ref[...] = (vals * absmax_ref[...][..., None]).astype(out_ref.dtype)
+    out_ref[0] = (vals * absmax_ref[0]).astype(out_ref.dtype)
 
 
 def _gather_pallas(pages_codes, pages_absmax, page_table, *, bits, dtype,
-                   interpret=True):
+                   interpret: bool):
     n_pages, page, KV, W = pages_codes.shape
     B, P = page_table.shape
     Dh = (W * 8) // bits
@@ -151,31 +162,27 @@ def _gather_pallas(pages_codes, pages_absmax, page_table, *, bits, dtype,
     # the per-slot length mask.
     table = jnp.clip(page_table, 0, n_pages - 1).astype(jnp.int32)
     qmap = common.padded_qmap(kv_qmap(bits))
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, P),
-            in_specs=[
-                pl.BlockSpec((1, page, KV, W),
-                             lambda b, p, t: (t[b, p], 0, 0, 0)),
-                pl.BlockSpec((1, page, KV),
-                             lambda b, p, t: (t[b, p], 0, 0)),
-                pl.BlockSpec((1, common.CODEBOOK_SIZE),
-                             lambda b, p, t: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, page, KV, Dh),
-                                   lambda b, p, t: (b, p, 0, 0)),
-        )
-        return pl.pallas_call(
-            functools.partial(_gather_kernel, bits=bits),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, P * page, KV, Dh), dtype),
-            interpret=interpret,
-        )(table, pages_codes, pages_absmax, qmap)
-    except ImportError:  # pallas-tpu unavailable: XLA path is the fallback
-        return _gather_jnp(pages_codes, pages_absmax, page_table,
-                           bits=bits, dtype=dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, P),
+        in_specs=[
+            pl.BlockSpec((1, page, KV, W),
+                         lambda b, p, t: (t[b, p], 0, 0, 0)),
+            # absmax carries a trailing unit dim so the block's last two
+            # dims (KV, 1) stay a legal 2-D tile broadcast over Dh.
+            pl.BlockSpec((1, page, KV, 1),
+                         lambda b, p, t: (t[b, p], 0, 0, 0)),
+            common.smem_spec(),
+        ],
+        out_specs=pl.BlockSpec((1, page, KV, Dh),
+                               lambda b, p, t: (b, p, 0, 0)),
+    )
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, bits=bits),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, P * page, KV, Dh), dtype),
+        interpret=interpret,
+    )(table, pages_codes, pages_absmax[..., None], qmap)
 
 
 def _gather_jnp(pages_codes, pages_absmax, page_table, *, bits, dtype):
@@ -191,8 +198,8 @@ def _gather_jnp(pages_codes, pages_absmax, page_table, *, bits, dtype):
 
 @functools.partial(jax.jit, static_argnames=("bits", "dtype", "impl"))
 def gather_pages(pages_codes: jax.Array, pages_absmax: jax.Array,
-                 page_table: jax.Array, *, bits: int, dtype=jnp.float32,
-                 impl: str = "jnp") -> jax.Array:
+                 page_table: jax.Array, *, bits: int, impl: str,
+                 dtype=jnp.float32) -> jax.Array:
     """Gather + dequantize every slot's pages.
 
     page_table: (B, P) int32 physical page per logical page (-1 =

@@ -9,7 +9,7 @@ All functions operate in the flat block domain: state tensors are
 optimizer update: it shares the 32-bit update math and norm finalization
 with ``fused_update.py`` (parity by construction) but keeps independent
 quantization mechanics (searchsorted + gather instead of the kernels'
-compare-sum + one-hot contraction).  It also implements the ablation modes
+compare-sum + select-chain lookup).  It also implements the ablation modes
 the Pallas path does not serve: tensor-wise (single absmax) quantization.
 It is registered in ``ops.py`` as the ``impl="jnp"`` entry for every
 algorithm — the only surviving form of the old multi-pass jnp fallback.
@@ -99,13 +99,10 @@ def segment_scales_ref(
     two = spec.n_states == 2
     p = p.astype(jnp.float32)
     g = g.astype(jnp.float32) * jnp.asarray(gnorm_scale, jnp.float32)
-    s = dict(lr=jnp.asarray(lr, jnp.float32),
-             beta1=jnp.asarray(beta1, jnp.float32),
-             beta2=jnp.asarray(beta2, jnp.float32),
-             eps=jnp.asarray(eps, jnp.float32),
-             weight_decay=jnp.asarray(weight_decay, jnp.float32),
-             step=jnp.asarray(step, jnp.float32),
-             tensor_scale=jnp.float32(1.0))
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    s = fu.update_scalars(lr=f32(lr), beta1=f32(beta1), beta2=f32(beta2),
+                          eps=f32(eps), weight_decay=f32(weight_decay),
+                          step=f32(step))
     m = dequantize_ref(codes_m, absmax_m, qmap_m)
     r = dequantize_ref(codes_r, absmax_r, qmap_r) if two else None
     segments = tuple(segments) if segments else ((0, n_blocks),)
@@ -151,13 +148,10 @@ def fused_update_ref(
     two = spec.n_states == 2
     p = p.astype(jnp.float32)
     g = g.astype(jnp.float32) * jnp.asarray(gnorm_scale, jnp.float32)
-    s = dict(lr=jnp.asarray(lr, jnp.float32),
-             beta1=jnp.asarray(beta1, jnp.float32),
-             beta2=jnp.asarray(beta2, jnp.float32),
-             eps=jnp.asarray(eps, jnp.float32),
-             weight_decay=jnp.asarray(weight_decay, jnp.float32),
-             step=jnp.asarray(step, jnp.float32),
-             tensor_scale=jnp.float32(1.0))
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    s = fu.update_scalars(lr=f32(lr), beta1=f32(beta1), beta2=f32(beta2),
+                          eps=f32(eps), weight_decay=f32(weight_decay),
+                          step=f32(step))
 
     m = dequantize_ref(codes_m, absmax_m, qmap_m)
     r = dequantize_ref(codes_r, absmax_r, qmap_r) if two else None

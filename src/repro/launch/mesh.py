@@ -5,27 +5,20 @@ from __future__ import annotations
 import jax
 
 
-def _mesh(shape, axes):
-    # jax < 0.5 has no jax.sharding.AxisType; Auto is the default there,
-    # so simply omit the kwarg (passing it raises AttributeError and took
-    # the whole dry-run harness down with it).
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, *, devices=None):
+    """The one mesh constructor of the repo: every axis Auto.  jax's own
+    ``make_mesh`` defaults to Explicit axes, under which the optimizer's
+    block-domain reshapes raise ``ShardingTypeError``."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod (TPU v5e pod), or 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / smoke / single-host)."""
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():

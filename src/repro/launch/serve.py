@@ -39,6 +39,8 @@ def build_requests(args, vocab_size):
 
 def main(argv=None):
     import jax
+    from repro.kernels import ops
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro import telemetry as tel
     from repro.serve.engine import ServeConfig, ServeEngine
@@ -61,7 +63,8 @@ def main(argv=None):
                     help="concurrent decode slots (the decode batch)")
     ap.add_argument("--serve-max-pages-per-seq", type=int, default=16)
     ap.add_argument("--serve-impl", choices=("jnp", "interpret", "pallas"),
-                    default="jnp", help="gather-dequant kernel impl")
+                    default=None, help="gather-dequant kernel impl "
+                    "(default: pallas on TPU, jnp elsewhere)")
     ap.add_argument("--streams", type=int, default=8,
                     help="number of concurrent request streams")
     ap.add_argument("--prompt-lens", default="8,16,24",
@@ -75,6 +78,7 @@ def main(argv=None):
     ap.add_argument("--out", default=None,
                     help="telemetry JSONL path (schema repro.telemetry.v1)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = cfgs.get_config(args.arch)
     if args.reduce:
@@ -110,7 +114,8 @@ def main(argv=None):
             cfg, params, SchedulerConfig(kv=kv,
                                          temperature=args.temperature,
                                          seed=args.seed,
-                                         impl=args.serve_impl),
+                                         impl=(args.serve_impl or
+                                               ops.default_impl())),
             registry=reg)
         results = eng.serve(reqs)
         summary = {"engine": "paged", "kv_bits": kv.kv_bits,

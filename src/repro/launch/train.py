@@ -7,8 +7,7 @@
 Fault tolerance: resumes from the latest checkpoint in --ckpt-dir
 automatically; SIGTERM/SIGINT triggers checkpoint-and-exit (preemption
 handling); per-step wall times are z-score-monitored and logged as straggler
-warnings (on multi-host this feeds the restart policy; see
-scripts/launch_with_retries.sh for the supervisor loop).
+warnings (on multi-host this feeds the restart policy).
 """
 from __future__ import annotations
 
@@ -25,13 +24,14 @@ import numpy as np
 from repro.configs import base as cfgs
 from repro.core.optim import make_optimizer
 from repro.data.pipeline import DataConfig, SyntheticLMPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro import telemetry as tel
 from repro.telemetry import tracing
 from repro.train import checkpoint as ckpt
 from repro.train import loop as train_loop
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-lm-209m")
     ap.add_argument("--optimizer", default="adam8")
@@ -80,8 +80,16 @@ def main(argv=None):
                          "(DESIGN.md §16)")
     ap.add_argument("--flight-ring", type=int, default=64,
                     help="flight-recorder ring length (steps)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def setup(args: argparse.Namespace):
+    """(cfg, data pipeline, optimizer, its make_optimizer kwargs,
+    TrainHyper) for a run — the launcher's whole configuration, so a caller
+    can rebuild the same run, or a variant of it, from the library API
+    (``make_optimizer(args.optimizer, **opt_kw)``,
+    ``train_loop.jit_train_step(cfg, opt, hyper)``).  Compute is forced to
+    f32."""
     cfg = cfgs.get_config(args.arch)
     over = {"param_dtype": "float32", "compute_dtype": "float32",
             "remat": "none"}
@@ -117,12 +125,18 @@ def main(argv=None):
         opt_kw["telemetry_every"] = args.telemetry_every
     if args.sentinel:
         opt_kw["sentinel"] = True
-    opt = make_optimizer(args.optimizer, lr=args.lr, weight_decay=0.0,
-                         **opt_kw)
     hyper = train_loop.TrainHyper(
         microbatches=args.microbatches,
         lr_schedule=train_loop.warmup_cosine(args.lr, args.warmup,
                                              args.steps))
+    opt_kw = dict(lr=args.lr, weight_decay=0.0, **opt_kw)
+    return cfg, pipe, make_optimizer(args.optimizer, **opt_kw), opt_kw, hyper
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    cfg, pipe, opt, _, hyper = setup(args)
 
     # Telemetry (DESIGN.md §14): a typed registry over a JSONL sink, with
     # trace-time phase annotations enabled BEFORE the step is traced so the

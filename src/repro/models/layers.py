@@ -100,12 +100,13 @@ class PagedContext:
                 step per slot; -1 = inactive slot (its append is dropped
                 and its attention masks every key).
     impl      : gather-dequant kernel implementation (static; "jnp" XLA
-                oracle, "interpret"/"pallas" the Pallas kernel).
+                oracle, "interpret"/"pallas" the Pallas kernel; no
+                default — the serving engine passes the platform's path).
     """
 
     page_table: jax.Array
     positions: jax.Array
-    impl: str = "jnp"
+    impl: str
 
     def tree_flatten(self):
         return (self.page_table, self.positions), (self.impl,)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.errors import ConfigError
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.models import model as M
 from repro.serve import engine as engine_lib
@@ -60,7 +61,9 @@ class SchedulerConfig:
     kv: PagedKVConfig = dataclasses.field(default_factory=PagedKVConfig)
     temperature: float = 0.0       # 0 => greedy
     seed: int = 0
-    impl: str = "jnp"              # gather-dequant kernel (jnp|interpret)
+    # gather-dequant kernel (pallas|interpret|jnp); the platform's own
+    # path by default, so a TPU runs the Pallas kernel
+    impl: str = dataclasses.field(default_factory=lambda: ops.default_impl())
 
 
 class ContinuousBatchingEngine:
@@ -190,7 +193,8 @@ class ContinuousBatchingEngine:
         logits, dense = self._prefill_fn(P)(
             self.params, jnp.asarray(np.asarray(req.prompt, np.int32)[None]))
         self.caches = self._commit_fn(P)(
-            self.caches, dense, slot, jnp.asarray(self.kv.page_table[slot]))
+            self.caches, dense, slot,
+            jnp.asarray(self.kv.page_table[slot].copy()))
         tok0 = self._sample1(logits[0, -1], req.rid, 0)   # device scalar
         self._last_tok = self._last_tok.at[slot].set(tok0)
         # chain = where each generated token lives, without syncing:
@@ -287,8 +291,11 @@ class ContinuousBatchingEngine:
                 to_edge = (len(self.kv.slots[slot].pages) * kvc.page_size
                            - self.kv.slots[slot].position)
                 k = min(x for x in (k, to_done, to_edge) if x is not None)
-            table = jnp.asarray(self.kv.page_table)
-            pos = jnp.asarray(self.kv.positions)
+            # Upload COPIES: on the CPU backend a device array may alias
+            # the numpy buffer it was made from, and the host mutates the
+            # table and positions while queued decode steps still read them.
+            table = jnp.asarray(self.kv.page_table.copy())
+            pos = jnp.asarray(self.kv.positions.copy())
             d_rids, d_gen = jnp.asarray(rids), jnp.asarray(gen)
             for _ in range(k):
                 self._last_tok, self.caches, pos, d_gen = self._decode(

@@ -181,7 +181,6 @@ def shard_map_over_spans(mesh: Mesh, axes, part, fn, spans, consts=()):
     Outputs must be span-shaped arrays; they come back unpadded to
     ``part.total`` rows.
     """
-    from jax.experimental.shard_map import shard_map
     import jax.numpy as jnp
 
     pad = part.padded_total - part.total
@@ -210,8 +209,8 @@ def shard_map_over_spans(mesh: Mesh, axes, part, fn, spans, consts=()):
         out_shapes = jax.eval_shape(inner, *local_args, *consts)
     out_specs = tuple(owned_span_spec(len(o.shape), axes)
                       for o in out_shapes)
-    outs = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*arrays, *consts)
+    outs = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*arrays, *consts)
     return tuple(o[:part.total] for o in outs)
 
 
@@ -244,7 +243,6 @@ def owner_routed(mesh: Mesh, axes, owner: int, fn, args):
     broadcasts to the replicas via a psum against zeros.  All result
     leaves round-trip through f32 (exact for uint8 codes and f32 state),
     so the broadcast is bit-exact."""
-    from jax.experimental.shard_map import shard_map
     import jax.numpy as jnp
     from repro.kernels import ops as _kops
 
@@ -271,9 +269,9 @@ def owner_routed(mesh: Mesh, axes, owner: int, fn, args):
         return jax.tree_util.tree_map(lambda x, sd: x.astype(sd.dtype),
                                       outf, out_tree)
 
-    return shard_map(routed, mesh=mesh,
-                     in_specs=tuple(P() for _ in args),
-                     out_specs=P(), check_rep=False)(*args)
+    return jax.shard_map(routed, mesh=mesh,
+                         in_specs=tuple(P() for _ in args),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def opt_state_shardings(abstract_opt_state, param_shard_tree, mesh: Mesh,

@@ -22,10 +22,12 @@ history when clipping is off) are recorded in the manifest and restored
 as ``None``.
 
 Bit-packed sub-byte states (``PackedCodes``, DESIGN.md §9) are stored as
-their packed uint8 words with a ``"packed": {"bits", "n_codes"}`` manifest
-annotation; restore validates the annotation against the template's static
-format (a 4-bit checkpoint cannot silently load as 5-bit — same byte
-count, different codes) and re-wraps the array.  Because packing is a
+their packed uint8 words with a ``"packed": {"bits", "n_codes", "layout"}``
+manifest annotation; restore validates the annotation against the
+template's static format (a 4-bit checkpoint cannot silently load as 5-bit
+— same byte count, different codes; bytes packed under another layout than
+``lowbit.PACK_LAYOUT``, or with no layout tag, are refused with a
+``FormatError``) and re-wraps the array.  Because packing is a
 per-block layout detail and the full logical array is stored, packed
 leaves stay elastic: the same checkpoint restores onto any mesh.
 
@@ -54,7 +56,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from repro.core.lowbit import PackedCodes
+from repro.core.lowbit import PACK_LAYOUT, PackedCodes
+from repro.errors import FormatError
 
 Pytree = Any
 
@@ -121,7 +124,8 @@ def save(ckpt_dir: str, step: int, tree: Pytree, *, keep_last: int = 3) -> str:
             name = f"a{i}"
             entry = {"key": key, "name": name}
             if _is_packed(leaf):
-                entry["packed"] = {"bits": leaf.bits, "n_codes": leaf.n_codes}
+                entry["packed"] = {"bits": leaf.bits, "n_codes": leaf.n_codes,
+                                   "layout": PACK_LAYOUT}
                 leaf = leaf.packed
             arrays[name] = np.asarray(jax.device_get(leaf))
             entry.update(dtype=str(arrays[name].dtype),
@@ -212,6 +216,12 @@ def restore(ckpt_dir: str, step: int, template: Pytree,
                     f"{key}: checkpoint packs {saved['bits']}-bit x "
                     f"{saved['n_codes']} codes; template expects "
                     f"{packed_tmpl.bits}-bit x {packed_tmpl.n_codes}")
+            if saved.get("layout") != PACK_LAYOUT:
+                raise FormatError(
+                    f"{key}: checkpoint packs codes in layout "
+                    f"{saved.get('layout')!r}; this code unpacks "
+                    f"{PACK_LAYOUT!r} only (the bytes would decode to "
+                    f"scrambled codes)")
             tmpl = packed_tmpl.packed
         elif saved is not None:
             raise ValueError(

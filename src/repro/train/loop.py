@@ -323,11 +323,10 @@ _contracts.register(
     doc="no f64 anywhere in the jitted step (§6 master-dtype policy)")
 _contracts.register(
     "train_step.collective_order", "step",
+    # markers: grads pinned into the owned-span layout -> reduce-scatter
+    # boundary (span entry) -> all-gather boundary (span exit)
     lambda low, cell: (_contracts.check_collective_order(
-        low.text,
-        "{devices=",                # grads pinned into the owned-span layout
-        "@SPMDFullToShardShape",    # reduce-scatter boundary: span entry
-        "@SPMDShardToFullShape")    # all-gather boundary: span exit
+        low.text, *_contracts.SPMD_BOUNDARY_MARKERS)
         if getattr(cell, "shard_grads", False) else None),
     doc="ZeRO-2 step shape (§13): grad scatter pin -> span-local fused "
         "update (shard_map body) -> span exit; the implicit collectives "

@@ -15,6 +15,7 @@ import pytest
 from repro.configs import base
 from repro.data.pipeline import DataConfig, SyntheticLMPipeline
 from repro.train import loop as L
+from repro.launch.mesh import make_mesh
 
 
 def tiny_cfg(d_model=64, n_layers=2, vocab_size=128):
@@ -33,7 +34,7 @@ def mesh_of(n: int, axis: str = "data"):
     if jax.device_count() < n:
         pytest.skip(f"needs {n} devices "
                     f"(xla_force_host_platform_device_count)")
-    return jax.make_mesh((n,), (axis,))
+    return make_mesh((n,), (axis,))
 
 
 def tiny_train(opt, steps: int, *, cfg=None, pipe=None, seed=0, trace=()):

@@ -307,7 +307,7 @@ def test_mutation_promote_f64_trips_no_dtype():
     # clean check must use the production trace mode.
     clean = runner.lower_update("adamw", 8)
     assert contracts.check_no_dtype(clean.text, "f64")[0] is True
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         with mutations.seeded("promote_f64"):
             mutated = runner.lower_update("adamw", 8)
     ok, detail = contracts.check_no_dtype(mutated.text, "f64")

@@ -13,6 +13,7 @@ from repro.core.optim import make_optimizer
 from repro.data.pipeline import DataConfig, SyntheticLMPipeline
 from repro.train import checkpoint as C
 from repro.train import loop as L
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture
@@ -84,7 +85,7 @@ def test_elastic_restore_new_sharding(setup, mesh_shape):
     if jax.device_count() < n:
         pytest.skip("needs more forced host devices")
     C.save(d, 3, state)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     shardings = jax.tree_util.tree_map(lambda x: sh, state)
     state_b = C.restore(d, 3, jax.eval_shape(lambda s: s, state), shardings)

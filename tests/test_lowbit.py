@@ -11,12 +11,14 @@ import numpy as np
 import pytest
 
 from repro.core import qmap
-from repro.core.lowbit import (SUPPORTED_BITS, CodeFormat, PackedCodes,
-                               pack_codes, packed_width, unpack_codes)
-from repro.errors import ConfigError
+from repro.core.lowbit import (PACK_LAYOUT, SUPPORTED_BITS, CodeFormat,
+                               PackedCodes, pack_codes, packed_width,
+                               unpack_codes)
+from repro.errors import ConfigError, FormatError
 from repro.core.optim import (Full32Leaf, OptimConfig, Quant8Leaf,
                               make_optimizer, unpool_state)
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------------------- qmaps
@@ -62,6 +64,19 @@ def test_pack_unpack_roundtrip_odd_block_counts(bits, n_blocks):
         assert packed.dtype == jnp.uint8
         out = unpack_codes(packed, bits)
         np.testing.assert_array_equal(np.asarray(out), codes)
+
+
+@pytest.mark.parametrize("bits,codes,packed", [
+    # 4-bit: first half of the row in the high nibbles, second in the low
+    (4, [1, 2, 3, 4], [0x13, 0x24]),
+    # 6-bit: four lane chunks, one per code, three bytes of bitstream
+    (6, [1, 2, 3, 4], [0b00000100, 0b00100000, 0b11000100]),
+])
+def test_pack_layout_is_lane_chunked(bits, codes, packed):
+    """The lane-chunked layout: position j of every chunk forms one group,
+    so packing needs only static lane slices (the Mosaic-lowerable form)."""
+    got = pack_codes(jnp.asarray([codes]), bits)
+    np.testing.assert_array_equal(np.asarray(got)[0], packed)
 
 
 def test_pack_unpack_roundtrip_hypothesis():
@@ -295,7 +310,7 @@ def test_checkpoint_packed_roundtrip_elastic(tmp_path):
     assert packed_entries and all(e["packed"]["bits"] == 4
                                   for e in packed_entries)
     # elastic restore onto an explicit (degenerate) mesh placement
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     shardings = jax.tree_util.tree_map(lambda x: sh, st)
     st_b = C.restore(d, 3, jax.eval_shape(lambda s: s, st), shardings)
@@ -337,11 +352,39 @@ def test_checkpoint_packed_bits_mismatch_rejected(tmp_path):
         C.restore(d, 2, jax.eval_shape(lambda: opt4.init(params)))
 
 
+@pytest.mark.parametrize("layout", [None, "nibble_interleaved"])
+def test_checkpoint_packed_layout_mismatch_rejected(tmp_path, layout):
+    """Packed bytes are only meaningful under the layout that wrote them: a
+    manifest whose packed entries carry no layout tag (written before the
+    tag existed) or another layout is refused, never unpacked."""
+    from repro.train import checkpoint as C
+    params = {"w": jnp.ones((64, 64))}
+    opt = make_optimizer("adam8", lr=1e-2, min_8bit_size=256,
+                         override_32bit=lambda p: False, state_bits=(4, 8))
+    d = str(tmp_path)
+    final = C.save(d, 1, opt.init(params))
+    path = os.path.join(final, "manifest.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    packed_entries = [e for e in manifest["index"] if "packed" in e]
+    assert packed_entries and all(e["packed"]["layout"] == PACK_LAYOUT
+                                  for e in packed_entries)
+    for e in packed_entries:
+        if layout is None:
+            del e["packed"]["layout"]
+        else:
+            e["packed"]["layout"] = layout
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    with pytest.raises(FormatError, match="layout"):
+        C.restore(d, 1, jax.eval_shape(lambda: opt.init(params)))
+
+
 def test_opt_state_shardings_packed_block_axis():
     """Sharding rules treat packed codes like plain codes: the block-count
     axis is sharded over all mesh axes, the byte axis never is."""
     from repro.sharding import rules
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"w": jnp.zeros((64, 64))}
     opt = make_optimizer("adam8", lr=1e-3, min_8bit_size=256,
                          override_32bit=lambda p: False, state_bits=(4, 8))

@@ -282,7 +282,8 @@ def test_muon_checkpoint_interchange_on_mesh(tmp_path, state_bits, n_dev):
     # matrix momentum leaves: block dim over the mesh
     wshard = shardings.leaves["w"]
     got = wshard.codes_m.packed if state_bits else wshard.codes_m
-    assert got.spec[0] == ("data",)
+    # jax >= 0.9 normalizes a one-axis tuple entry to the bare axis name
+    assert got.spec[0] in ("data", ("data",))
     st_po = C.restore(d, 3, template, shardings)
     _assert_trees_equal(unpool_state(st_po).leaves, st.leaves,
                         "restored pooled != saved per-leaf")

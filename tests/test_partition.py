@@ -21,6 +21,7 @@ from repro.core.optim import (Quant8Leaf, make_optimizer, make_partition,
 from repro.kernels import ops
 from repro.train import checkpoint as C
 
+from repro.launch.mesh import make_mesh
 from helpers import assert_trees_equal, mesh_of
 
 
@@ -135,7 +136,7 @@ def test_partition_multi_pod_axes():
     and muon owner routing uses the combined (major-to-minor) index."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
-    mesh = jax.make_mesh((2, 2), ("pod", "data"))
+    mesh = make_mesh((2, 2), ("pod", "data"))
     kw = dict(lr=1e-2, min_8bit_size=1024, stochastic_rounding=True)
     opt = make_optimizer("adamw8", mesh=mesh, partition_axis="pod,data",
                          **kw)

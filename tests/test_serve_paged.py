@@ -138,6 +138,28 @@ def test_gather_pallas_interpret_matches_jnp(bits):
     assert float(jnp.abs(a - b).max()) == 0.0
 
 
+@pytest.mark.parametrize("bits", [8, 4])
+def test_gather_bf16_values_are_rounded_on_both_paths(bits):
+    """Gathered into bf16 and read back as f32 inside one jitted program
+    (what bf16 decode attention does), both impls give exactly the
+    bf16-rounded values: XLA may not drop the jnp path's rounding."""
+    key = jax.random.PRNGKey(3)
+    n_pages, page, KV, Dh = 6, 4, 2, 8
+    rows = jax.random.normal(key, (n_pages, page, KV, Dh))
+    codes, absmax = paged_kv.quantize_rows(rows, bits)
+    table = jnp.asarray([[3, 0, 5], [1, 4, 2]], jnp.int32)
+    want = np.asarray(paged_kv.gather_pages(
+        codes, absmax, table, bits=bits, impl="jnp")).astype(
+            jnp.bfloat16).astype(np.float32)
+    for impl in ("jnp", "interpret"):
+        got = jax.jit(lambda c, a, t: paged_kv.gather_pages(
+            c, a, t, bits=bits, impl=impl,
+            dtype=jnp.bfloat16).astype(jnp.float32) * 3.0)(
+                codes, absmax, table)
+        np.testing.assert_array_equal(np.asarray(got), want * 3.0,
+                                      err_msg=impl)
+
+
 def test_append_drops_inactive_slot_sentinel():
     """An out-of-range page id (the scheduler's inactive-slot sentinel)
     must be DROPPED by the append scatter — never clamped onto a live

@@ -108,3 +108,18 @@ def test_divisibility_always_respected():
             for a in axes:
                 prod *= PROD.shape[a]
             assert dim % prod == 0, (shape, logical, spec)
+
+
+def test_make_optimizer_refuses_explicit_axes():
+    """An Explicit-axis mesh (jax.make_mesh's default) is refused up front
+    with a ConfigError naming the axis type, not deep in a reshape."""
+    from repro.core.optim import make_optimizer
+    from repro.errors import ConfigError
+    from repro.launch.mesh import make_mesh
+
+    explicit = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Explicit,))
+    with pytest.raises(ConfigError, match="Explicit"):
+        make_optimizer("adamw8", mesh=explicit)
+    auto = make_mesh((1,), ("data",))
+    assert make_optimizer("adamw8", mesh=auto) is not None
