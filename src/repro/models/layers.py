@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.constrain import attn_score_dims, constrain
+from repro.telemetry import tracing as _tracing
 
 # --------------------------------------------------------------------- utils
 
@@ -272,18 +273,18 @@ def _paged_decode_attention(q, k, v, cfg, cache, paged):
     ppage = jnp.where(active & (ppage >= 0), ppage, n_pages)
     off = pos_c % page
     new_cache = dict(cache)
-    for name, row in (("k", k), ("v", v)):
-        new_cache[f"{name}_codes"], new_cache[f"{name}_absmax"] = \
-            paged_kv.append_rows(cache[f"{name}_codes"],
-                                 cache[f"{name}_absmax"],
-                                 row[:, 0], ppage, off, bits)
+    with _tracing.annotate("kv_append"):
+        for name, row in (("k", k), ("v", v)):
+            new_cache[f"{name}_codes"], new_cache[f"{name}_absmax"] = \
+                paged_kv.append_rows(cache[f"{name}_codes"],
+                                     cache[f"{name}_absmax"],
+                                     row[:, 0], ppage, off, bits)
     dt = q.dtype
-    k_all = paged_kv.gather_pages(new_cache["k_codes"],
-                                  new_cache["k_absmax"], paged.page_table,
-                                  bits=bits, dtype=dt, impl=paged.impl)
-    v_all = paged_kv.gather_pages(new_cache["v_codes"],
-                                  new_cache["v_absmax"], paged.page_table,
-                                  bits=bits, dtype=dt, impl=paged.impl)
+    with _tracing.annotate("kv_gather"):
+        k_all, v_all = (paged_kv.gather_pages(
+            new_cache[f"{name}_codes"], new_cache[f"{name}_absmax"],
+            paged.page_table, bits=bits, dtype=dt, impl=paged.impl)
+            for name in ("k", "v"))
     L = k_all.shape[1]
     idx = jnp.arange(L)[None, :]
     valid = active[:, None] & (idx <= pos_c[:, None])

@@ -25,6 +25,7 @@ import numpy as np
 from repro.models import embedding as emb
 from repro.models import layers, moe, recurrent, xlstm
 from repro.models.constrain import constrain, constrain_block_params
+from repro.telemetry import tracing as _tracing
 
 Pytree = Any
 
@@ -77,24 +78,26 @@ def _apply_block(p, x, cfg, kind: str, *, positions, state=None,
     layers.PagedContext during paged-KV decode (DESIGN.md §17) — only attn
     blocks consume it; recurrent kinds keep their per-slot dense state."""
     metrics = {}
+
+    def ffn(h):                        # -> (out, metrics)
+        with _tracing.annotate("mlp"):
+            if "moe" in p:
+                return moe.apply_moe(p["moe"], h, cfg)
+            return layers.apply_mlp(p["mlp"], h, cfg), {}
+
     if kind == "attn":
         h = layers.apply_norm(p["norm1"], x, cfg.norm_type)
-        a_out, new_cache = layers.apply_attention(
-            p["attn"], h, cfg, positions=positions, cache=state,
-            cache_len=cache_len, paged=paged)
+        with _tracing.annotate("attention"):
+            a_out, new_cache = layers.apply_attention(
+                p["attn"], h, cfg, positions=positions, cache=state,
+                cache_len=cache_len, paged=paged)
         if cfg.parallel_block:
-            if cfg.is_moe:
-                f_out, metrics = moe.apply_moe(p["moe"], h, cfg)
-            else:
-                f_out = layers.apply_mlp(p["mlp"], h, cfg)
+            f_out, metrics = ffn(h)
             x = x + a_out + f_out
         else:
             x = x + a_out
             h2 = layers.apply_norm(p["norm2"], x, cfg.norm_type)
-            if cfg.is_moe:
-                f_out, metrics = moe.apply_moe(p["moe"], h2, cfg)
-            else:
-                f_out = layers.apply_mlp(p["mlp"], h2, cfg)
+            f_out, metrics = ffn(h2)
             x = x + f_out
         return x, new_cache, metrics
     if kind == "rglru":
@@ -103,7 +106,7 @@ def _apply_block(p, x, cfg, kind: str, *, positions, state=None,
         x = x + r_out
         if cfg.d_ff:
             h2 = layers.apply_norm(p["norm2"], x, cfg.norm_type)
-            x = x + layers.apply_mlp(p["mlp"], h2, cfg)
+            x = x + ffn(h2)[0]
         return x, new_state, metrics
     if kind in ("mlstm", "slstm"):
         h = layers.apply_norm(p["norm1"], x, cfg.norm_type)

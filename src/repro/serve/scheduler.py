@@ -28,6 +28,17 @@ scheduler only needs token COUNTS, which it knows, to admit/evict/
 complete — never token values), so decode steps queue back-to-back with
 no per-step host round-trip; the host blocks once per completion (the
 latency observation) and copies the token matrix once per ``serve``.
+
+Observability (DESIGN.md §14): with phase tracing on, ``serve`` opens
+host spans on the profiler's clock — ``tel.serve`` around the call;
+``tel.serve.admit`` (``rid``) with its ``.prefill`` and ``.commit``
+dispatches; ``tel.serve.extend`` (page extension, eviction);
+``tel.serve.upload``; ``tel.serve.burst`` (``k``); ``tel.serve.complete``
+(``rid``) with its ``.sync``; ``tel.serve.collect``.  With a registry,
+each burst of ``k`` decode steps counts, on the host and with no sync,
+``serve/sched/{bursts, decode_steps, slot_steps}`` (live slots × k) and
+``serve/kv/pages_live`` (their allocated pages × k) against
+``serve/kv/pages_gathered`` (the whole table × k: what the gather reads).
 """
 from __future__ import annotations
 
@@ -47,6 +58,7 @@ from repro.models import layers as L
 from repro.models import model as M
 from repro.serve import engine as engine_lib
 from repro.serve.kvcache import PagedKVCache, PagedKVConfig, kv_bytes_per_token
+from repro.telemetry.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,26 +197,30 @@ class ContinuousBatchingEngine:
                 f"request {req.rid}: max_new_tokens must be positive")
 
     def _admit(self, req: Request) -> bool:
-        P = len(req.prompt)
-        slot = self.kv.admit(req.rid, P)
-        if slot is None:
-            return False
-        t0 = time.perf_counter()
-        logits, dense = self._prefill_fn(P)(
-            self.params, jnp.asarray(np.asarray(req.prompt, np.int32)[None]))
-        self.caches = self._commit_fn(P)(
-            self.caches, dense, slot,
-            jnp.asarray(self.kv.page_table[slot].copy()))
-        tok0 = self._sample1(logits[0, -1], req.rid, 0)   # device scalar
-        self._last_tok = self._last_tok.at[slot].set(tok0)
-        # chain = where each generated token lives, without syncing:
-        # ("a", device_scalar) for the admission sample, ("s", step_idx)
-        # for decode steps (the slot row of that step's token vector)
-        self._live[req.rid] = {"req": req, "t0": t0, "n_out": 1,
-                               "chain": [("a", tok0)]}
-        self._count("serve/sched/admitted")
-        self._count("serve/prompt_tokens", P)
-        return True
+        with span("serve.admit", rid=req.rid):
+            P = len(req.prompt)
+            slot = self.kv.admit(req.rid, P)
+            if slot is None:
+                return False
+            t0 = time.perf_counter()
+            with span("serve.prefill", rid=req.rid):
+                logits, dense = self._prefill_fn(P)(
+                    self.params,
+                    jnp.asarray(np.asarray(req.prompt, np.int32)[None]))
+            with span("serve.commit", rid=req.rid):
+                self.caches = self._commit_fn(P)(
+                    self.caches, dense, slot,
+                    jnp.asarray(self.kv.page_table[slot].copy()))
+            tok0 = self._sample1(logits[0, -1], req.rid, 0)   # device scalar
+            self._last_tok = self._last_tok.at[slot].set(tok0)
+            # chain = where each generated token lives, without syncing:
+            # ("a", device_scalar) for the admission sample, ("s", step_idx)
+            # for decode steps (the slot row of that step's token vector)
+            self._live[req.rid] = {"req": req, "t0": t0, "n_out": 1,
+                                   "chain": [("a", tok0)]}
+            self._count("serve/sched/admitted")
+            self._count("serve/prompt_tokens", P)
+            return True
 
     def _evict_youngest(self, waiting, protect=None) -> bool:
         """Preempt the youngest admitted request back to the queue front."""
@@ -219,20 +235,27 @@ class ContinuousBatchingEngine:
         return False
 
     def _complete(self, rid: int, done: dict):
-        st = self._live.pop(rid)
-        self.kv.release(rid)
-        # block on the request's last token: the one per-request device
-        # sync, and what makes the latency observation wall-clock-honest
-        last = st["chain"][-1]
-        jax.block_until_ready(last[1] if last[0] == "a" else self._last_tok)
-        done[rid] = st
-        self._observe_request((time.perf_counter() - st["t0"]) * 1e3)
-        self._count("serve/sched/completed")
-        self._count("serve/generated_tokens", st["n_out"])
+        with span("serve.complete", rid=rid):
+            st = self._live.pop(rid)
+            self.kv.release(rid)
+            # block on the request's last token: the one per-request device
+            # sync, and what makes the latency observation wall-clock-honest
+            last = st["chain"][-1]
+            with span("serve.sync", rid=rid):
+                jax.block_until_ready(last[1] if last[0] == "a" else
+                                      self._last_tok)
+            done[rid] = st
+            self._observe_request((time.perf_counter() - st["t0"]) * 1e3)
+            self._count("serve/sched/completed")
+            self._count("serve/generated_tokens", st["n_out"])
 
     # --------------------------------------------------------------- run
     def serve(self, requests) -> dict:
         """Run every request to completion; returns {rid: (n,) int32}."""
+        with span("serve"):
+            return self._serve(requests)
+
+    def _serve(self, requests) -> dict:
         for r in requests:
             self._validate(r)
         waiting = collections.deque(requests)
@@ -264,14 +287,15 @@ class ContinuousBatchingEngine:
                     waiting.popleft()          # the forced admit succeeded
                 continue
             # 3. make sure every active slot's write position has a page
-            for rid in list(self._live):
-                if rid not in self._live:      # evicted for a prior slot
-                    continue
-                while not self.kv.extend(rid):
-                    if not self._evict_youngest(waiting, protect=rid):
-                        raise ConfigError(
-                            f"request {rid} cannot extend with the pool to "
-                            f"itself — capacity validation is broken")
+            with span("serve.extend"):
+                for rid in list(self._live):
+                    if rid not in self._live:  # evicted for a prior slot
+                        continue
+                    while not self.kv.extend(rid):
+                        if not self._evict_youngest(waiting, protect=rid):
+                            raise ConfigError(
+                                f"request {rid} cannot extend with the pool "
+                                f"to itself — capacity validation is broken")
             self._gauges()
             # 4. run the next k decode steps back-to-back: scheduling can
             # only change at a completion or a page boundary, both known
@@ -281,28 +305,40 @@ class ContinuousBatchingEngine:
             gen = np.zeros((kvc.n_slots,), np.int32)
             snapshot = {}
             k = None
+            live_pages = 0
             for rid in self._live:
                 slot = self.kv.slot_of(rid)
                 st = self._live[rid]
                 rids[slot] = rid
                 gen[slot] = st["n_out"]
                 snapshot[rid] = slot
+                pages = len(self.kv.slots[slot].pages)
+                live_pages += pages
                 to_done = st["req"].max_new_tokens - st["n_out"]
-                to_edge = (len(self.kv.slots[slot].pages) * kvc.page_size
-                           - self.kv.slots[slot].position)
+                to_edge = pages * kvc.page_size - self.kv.slots[slot].position
                 k = min(x for x in (k, to_done, to_edge) if x is not None)
             # Upload COPIES: on the CPU backend a device array may alias
             # the numpy buffer it was made from, and the host mutates the
             # table and positions while queued decode steps still read them.
-            table = jnp.asarray(self.kv.page_table.copy())
-            pos = jnp.asarray(self.kv.positions.copy())
-            d_rids, d_gen = jnp.asarray(rids), jnp.asarray(gen)
-            for _ in range(k):
-                self._last_tok, self.caches, pos, d_gen = self._decode(
-                    self.params, self._last_tok, self.caches, table, pos,
-                    d_rids, d_gen)
-                step_toks.append(self._last_tok)
-                step_slots.append(snapshot)
+            with span("serve.upload"):
+                table = jnp.asarray(self.kv.page_table.copy())
+                pos = jnp.asarray(self.kv.positions.copy())
+                d_rids, d_gen = jnp.asarray(rids), jnp.asarray(gen)
+            with span("serve.burst", k=k):
+                for _ in range(k):
+                    self._last_tok, self.caches, pos, d_gen = self._decode(
+                        self.params, self._last_tok, self.caches, table, pos,
+                        d_rids, d_gen)
+                    step_toks.append(self._last_tok)
+                    step_slots.append(snapshot)
+            # work of the burst: every slot decodes and the gather reads
+            # every slot's whole table, live or not
+            self._count("serve/sched/bursts")
+            self._count("serve/sched/decode_steps", k)
+            self._count("serve/sched/slot_steps", len(snapshot) * k)
+            self._count("serve/kv/pages_live", live_pages * k)
+            self._count("serve/kv/pages_gathered",
+                        kvc.n_slots * kvc.max_pages_per_seq * k)
             # 5. advance host bookkeeping k steps, complete finished
             for rid, slot in snapshot.items():
                 st = self._live[rid]
@@ -312,17 +348,18 @@ class ContinuousBatchingEngine:
                     st["chain"].append(("s", len(step_toks) - k + j))
                 if st["n_out"] >= st["req"].max_new_tokens:
                     self._complete(rid, done)
-        # one bulk sync for every decode-step token vector
-        mat = np.asarray(jnp.stack(step_toks)) if step_toks else \
-            np.zeros((0, kvc.n_slots), np.int32)
-        results: dict = {}
-        n_gen = 0
-        for rid, st in done.items():
-            toks = [int(np.asarray(e[1])) if e[0] == "a" else
-                    int(mat[e[1], step_slots[e[1]][rid]])
-                    for e in st["chain"]]
-            results[rid] = np.asarray(toks, np.int32)
-            n_gen += len(toks)
+        with span("serve.collect"):
+            # one bulk sync for every decode-step token vector
+            mat = np.asarray(jnp.stack(step_toks)) if step_toks else \
+                np.zeros((0, kvc.n_slots), np.int32)
+            results: dict = {}
+            n_gen = 0
+            for rid, st in done.items():
+                toks = [int(np.asarray(e[1])) if e[0] == "a" else
+                        int(mat[e[1], step_slots[e[1]][rid]])
+                        for e in st["chain"]]
+                results[rid] = np.asarray(toks, np.int32)
+                n_gen += len(toks)
         wall = time.perf_counter() - t_serve
         if self.registry is not None and n_gen and wall > 0:
             self.registry.gauge("serve/tokens_per_s").set(n_gen / wall)

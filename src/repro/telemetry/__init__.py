@@ -4,8 +4,8 @@ Three pillars:
   * :mod:`repro.telemetry.qhealth` — scheduled quantization-health probes
     (saturation, codebook utilization, absmax drift, round-trip RMS);
   * :mod:`repro.telemetry.tracing` — step-phase annotations, trace-time
-    dispatch accounting, and the shared ``StepTimer`` (ms/step +
-    compile_s single definition);
+    dispatch accounting, host spans, and the shared ``StepTimer``
+    (ms/step + compile_s single definition);
   * :mod:`repro.telemetry.registry` / :mod:`repro.telemetry.export` —
     typed metrics (counter/gauge/histogram) and the JSONL / in-memory /
     BENCH-trajectory sinks behind them;
@@ -32,7 +32,7 @@ from repro.telemetry.tracing import (StepTimer, annotate, drain_phase_events,
                                      host_phase, phase_tracing,
                                      phase_tracing_enabled,
                                      reset_trace_events, set_phase_tracing,
-                                     trace_event_dict, trace_events)
+                                     span, trace_event_dict, trace_events)
 
 __all__ = [
     "SCHEMA", "BenchJsonSink", "InMemorySink", "JsonlSink",
@@ -43,5 +43,5 @@ __all__ = [
     "QHealthProbe", "MetricRegistry", "StepTimer", "annotate",
     "drain_phase_events", "host_phase", "phase_tracing",
     "phase_tracing_enabled", "reset_trace_events", "set_phase_tracing",
-    "trace_event_dict", "trace_events",
+    "span", "trace_event_dict", "trace_events",
 ]

@@ -1,25 +1,33 @@
 """Step-phase tracing + the shared step-timing helper (DESIGN.md §14).
 
-Two distinct clocks live here:
+One process-wide flag (:func:`set_phase_tracing`, default off) switches
+the scopes and the spans; off, they add nothing to a compiled program and
+open nothing on the host.
 
-**Trace-time phase annotations** — :func:`annotate` wraps each phase of
-the optimizer step (blockwise quant/dequant, per-bucket ``fused_update``
-dispatches, Newton–Schulz gram/apply passes, reduce-scatter, deferred
-all-gather).  Annotations are OFF by default and the wrapper is then a
-literal no-op (``yield`` and nothing else), so the default jitted
-computation — and its StableHLO text — is byte-identical to a build
-without telemetry (the zero-overhead guard in tests/test_telemetry.py
-pins this).  When enabled via :func:`set_phase_tracing`, each ``annotate``
-block:
+**Device scopes** — :func:`annotate` names a region of a traced function
+(the optimizer step's phases: blockwise quant/dequant, per-bucket
+``fused_update`` dispatches, Newton–Schulz gram/apply passes,
+reduce-scatter, deferred all-gather; the model's ``attention``/``mlp``
+and the paged cache's ``kv_append``/``kv_gather``).  Off, the wrapper is
+a literal no-op (``yield`` and nothing else), so the jitted computation —
+and its StableHLO text — is byte-identical to a build without telemetry
+(the zero-overhead guards in tests/test_telemetry.py and
+tests/test_serve_tracing.py pin this).  On, each ``annotate`` block:
 
-  * enters ``jax.named_scope`` (names the ops for XLA/HLO dumps) and
-    ``jax.profiler.TraceAnnotation`` (names the region for the profiler
-    timeline), and
+  * enters ``jax.named_scope("tel.<phase>")``, which rides the ops'
+    ``op_name`` metadata into the compiled HLO (it changes no value and
+    no instruction name), and
   * records a *trace event*: ``(phase, fused dispatches inside, trace
     wall-clock)``.  Under jit this fires at trace time, so one compiled
     step yields one dispatch-accounted phase list — exactly the launches
     baked into the executable (the same convention as
     ``ops.fused_update_count``; DESIGN.md §10).
+
+**Host spans** — :func:`span` opens a ``jax.profiler.TraceAnnotation``
+named ``tel.<name>`` around host work at run time (the serving engine's
+admission, page extension, uploads, decode bursts and completions), so
+it lands on the profiler's host plane on the device trace's clock.  It
+keeps nothing in Python, so a long-running server does not grow.
 
 **Host wall-clock** — :class:`StepTimer` is the single definition of
 ``ms/step`` and ``compile_s``: the first executed step pays jit tracing +
@@ -28,7 +36,7 @@ accumulate into ``ms/step``, and a trailing-window z-score flags
 stragglers.  ``train/loop.py``-era call sites (``launch/train.py``,
 quickstart, benchmarks) all use this one helper instead of inlining the
 split.  :func:`host_phase` times host-side phases (probe runs, eval) into
-"phase" events for the JSONL timeline.
+"phase" events for the JSONL timeline, under the same span.
 """
 from __future__ import annotations
 
@@ -80,11 +88,11 @@ def reset_trace_events() -> None:
 
 @contextlib.contextmanager
 def annotate(phase: str):
-    """Name one step phase.  A no-op unless phase tracing is enabled —
-    keeping the default trace, and therefore the compiled step, untouched.
-    Enabled, it enters ``jax.named_scope``/``TraceAnnotation`` and records
-    a trace event with the number of fused_update dispatches issued inside
-    the region (trace-time accounting, DESIGN.md §10)."""
+    """Name one region of a traced function.  A no-op unless phase tracing
+    is enabled — keeping the default trace, and therefore the compiled
+    program, untouched.  Enabled, it enters ``jax.named_scope`` and
+    records a trace event with the number of fused_update dispatches
+    issued inside the region (trace-time accounting, DESIGN.md §10)."""
     if not _PHASE_TRACING[0]:
         yield
         return
@@ -92,12 +100,7 @@ def annotate(phase: str):
     from repro.kernels import ops  # lazy: ops imports this module
     n0 = ops.fused_update_count()
     t0 = time.perf_counter()
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(f"tel.{phase}"))
-        try:
-            stack.enter_context(jax.profiler.TraceAnnotation(f"tel.{phase}"))
-        except Exception:
-            pass  # profiler backend unavailable; named_scope still applies
+    with jax.named_scope(f"tel.{phase}"):
         yield
     _TRACE_EVENTS.append({
         "phase": phase,
@@ -114,13 +117,25 @@ def trace_event_dict(step: int) -> dict:
 
 
 # ------------------------------------------------------ host-side timeline
+def span(name: str, **ids):
+    """A host span ``tel.<name>`` on the profiler's timeline, carrying
+    ``ids`` (say ``rid=3``) as its metadata, when phase tracing is
+    enabled; ``contextlib.nullcontext()`` otherwise.  Read at run time:
+    unlike :func:`annotate`, it needs no re-trace to switch."""
+    if not _PHASE_TRACING[0]:
+        return contextlib.nullcontext()
+    import jax
+    return jax.profiler.TraceAnnotation(f"tel.{name}", **ids)
+
+
 @contextlib.contextmanager
 def host_phase(phase: str, step: int = -1):
     """Record host wall-clock for one phase into the pending "phase" event
-    list (drained by :func:`drain_phase_events`)."""
+    list (drained by :func:`drain_phase_events`), under :func:`span`."""
     t0 = time.perf_counter()
     try:
-        yield
+        with span(phase):
+            yield
     finally:
         _PHASE_EVENTS.append({"kind": "phase", "step": int(step),
                               "phase": phase,

@@ -447,6 +447,36 @@ def test_host_phase_timeline():
     assert tracing.drain_phase_events() == []     # drained
 
 
+def test_annotate_is_device_only_and_host_phase_opens_a_span(tmp_path):
+    """Under the profiler, tracing an annotated function writes no host
+    span (the scope belongs to the compiled ops, not to trace time),
+    while ``host_phase`` opens its run-time ``tel.`` span and keeps its
+    JSONL event."""
+    import glob
+    from jax.profiler import ProfileData
+
+    def f(x):
+        with tracing.annotate("scoped"):
+            return x * 2.0
+
+    with tracing.phase_tracing(True):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            jax.jit(f).lower(jnp.ones((4,))).compile()
+            with tracing.host_phase("probe", step=1):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        tracing.reset_trace_events()
+    [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("tel.")]
+    assert names == ["tel.probe"]
+    [ev] = tracing.drain_phase_events()
+    assert ev["phase"] == "probe" and ev["step"] == 1
+
+
 # ------------------------------------------------------------ StepTimer
 def test_step_timer_compile_split_and_straggler():
     t = tracing.StepTimer(window=5, z_threshold=3.0)
