@@ -53,6 +53,10 @@ PIPELINE_FACTOR = 2
 _F32 = 4
 _I32 = 4
 _U8 = 1
+# One codebook or boundary lane table (``common.table_spec``): a (2, 128)
+# f32 VMEM block, which fills a whole (8, 128) f32 tile.
+_SUBLANES = 8
+TABLE_BYTES = _SUBLANES * _kc.LANES * _F32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,8 +96,8 @@ class TileBudget:
 
 def _lookup_scratch(tile_elems: int) -> int:
     """The codebook search / lookup intermediates of kernels/common.py:
-    one tile-sized accumulator (i32 codes or f32 values) plus the
-    per-entry compare mask of the select chain."""
+    one tile-sized result (i32 codes or f32 values) plus the gathered
+    boundary or table entry of the lane-gather search."""
     return tile_elems * (_I32 + _F32)
 
 
@@ -117,7 +121,6 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
     code1 = rows * w1 * _U8          # (rows, w1) u8
     code2 = rows * w2 * _U8
     one = rows * 1 * _F32            # (rows, 1) f32 / i32
-    const = _kc.CODEBOOK_SIZE * _F32  # (256,) f32 in SMEM
     scal = _fu.N_SCALARS * _F32      # (N_SCALARS,) f32 in SMEM
 
     streamed_in = 2 * row + code1 + one            # p, g, codes_m, absmax_m
@@ -127,9 +130,9 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
         streamed_in += 2 * one                     # block_seeds, offsets
     if spec.needs_norms:
         streamed_in += one                         # tensor_scale slice
-    invariant = scal + 2 * const                   # scalars, qmap, bounds
+    invariant = scal + 2 * TABLE_BYTES             # scalars, qmap, bounds
     if two:
-        invariant += 2 * const
+        invariant += 2 * TABLE_BYTES
 
     streamed_out = row + code1 + one               # p', codes_m', absmax_m'
     if two:
@@ -157,14 +160,15 @@ def fused_update_tile(algo: str, *, rows: int = _kc.DEFAULT_ROWS,
 def quantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
                   ) -> TileBudget:
     """One ``quantize_blockwise`` grid step (blockwise_quant.py): in
-    (rows, bsz) f32 + (256,) SMEM codebook -> (rows, w) u8 + (rows, 1) f32."""
+    (rows, bsz) f32 + the boundary lane table -> (rows, w) u8 + (rows, 1)
+    f32."""
     bsz = block_size
     row = rows * bsz * _F32
     return TileBudget(
         kernel="blockwise_quant",
         streamed_in=row,
         streamed_out=rows * bsz * _U8 + rows * _F32,
-        invariant=2 * _kc.CODEBOOK_SIZE * _F32,     # codebook + bounds
+        invariant=TABLE_BYTES,                      # boundaries
         scratch=_lookup_scratch(rows * bsz),
         config={"rows": rows, "block_size": bsz})
 
@@ -172,13 +176,14 @@ def quantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
 def dequantize_tile(*, rows: int = _kc.DEFAULT_ROWS, block_size: int = 2048
                     ) -> TileBudget:
     """One ``dequantize_blockwise`` grid step (blockwise_dequant.py): in
-    (rows, bsz) u8 + (rows, 1) f32 + (256,) SMEM codebook -> (rows, bsz)."""
+    (rows, bsz) u8 + (rows, 1) f32 + the codebook lane table ->
+    (rows, bsz)."""
     bsz = block_size
     return TileBudget(
         kernel="blockwise_dequant",
         streamed_in=rows * bsz * _U8 + rows * _F32,
         streamed_out=rows * bsz * _F32,
-        invariant=_kc.CODEBOOK_SIZE * _F32,
+        invariant=TABLE_BYTES,
         scratch=_lookup_scratch(rows * bsz),
         config={"rows": rows, "block_size": bsz})
 

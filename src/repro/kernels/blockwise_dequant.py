@@ -1,7 +1,8 @@
 """Pallas TPU kernel: block-wise 8-bit dequantization.
 
-Codebook lookup is an exact select chain over an SMEM-resident codebook —
-the TPU analogue of the CUDA shared-memory LUT gather (DESIGN.md §3).
+Codebook lookup is an exact lane gather from a codebook held in vector
+registers (``common.decode``) — the TPU analogue of the CUDA
+shared-memory LUT gather (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -45,7 +46,7 @@ def dequantize_blockwise(
         in_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
             pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-            common.smem_spec(),
+            common.table_spec(),
         ],
         out_specs=pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, bsz), dtype),

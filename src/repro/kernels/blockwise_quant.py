@@ -48,7 +48,7 @@ def quantize_blockwise(
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),
-            common.smem_spec(),
+            common.table_spec(),
         ],
         out_specs=[
             pl.BlockSpec((rows, bsz), lambda i: (i, 0)),

@@ -362,7 +362,7 @@ def _make_update_kernel(spec: AlgoSpec, rows: int, bsz: int, stochastic: bool,
         g = g_ref[...].astype(jnp.float32) * s["gnorm_scale"]
         p = p_ref[...].astype(jnp.float32)
 
-        # ---- unpack sub-byte codes + dequantize (SMEM codebook) ----
+        # ---- unpack sub-byte codes + dequantize (lane-table codebook) ----
         m = common.decode(unpack_codes(c1_ref[...], bits_m),
                           qm1_ref, 1 << bits_m) * a1_ref[...]
         r = (common.decode(unpack_codes(c2_ref[...], bits_r),
@@ -462,13 +462,12 @@ def _norm_partials_pallas(spec: AlgoSpec, p, g, codes_m, absmax_m, codes_r,
     row_spec = pl.BlockSpec((rows, bsz), lambda i: (i, 0))
     code1_spec = pl.BlockSpec((rows, w1), lambda i: (i, 0))
     one_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
-    const_spec = scal_spec = common.smem_spec()
 
     norm_kernel = _make_norm_kernel(spec, rows, bsz, bits_m, bits_r)
-    in_specs = [scal_spec]
+    in_specs = [common.smem_spec()]
     args = [scalars]
     if spec.norm_kind == "lamb":
-        in_specs += [const_spec, const_spec]
+        in_specs += [common.table_spec(), common.table_spec()]
         args += [qm1, qm2]
     in_specs += [row_spec, row_spec]
     args += [p, g]
@@ -599,7 +598,7 @@ def fused_update_pallas(
     code1_spec = pl.BlockSpec((rows, w1), lambda i: (i, 0))
     code2_spec = pl.BlockSpec((rows, w2), lambda i: (i, 0)) if two else None
     one_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
-    const_spec = scal_spec = common.smem_spec()
+    table_spec = common.table_spec()
 
     qm1, b1 = common.padded_qmap(qmap_m), common.padded_bounds(qmap_m)
     if two:
@@ -626,7 +625,7 @@ def fused_update_pallas(
 
     kernel = _make_update_kernel(spec, rows, bsz, stochastic, bits_m, bits_r,
                                  sentinel)
-    in_specs = [scal_spec]
+    in_specs = [common.smem_spec()]
     args = [scalars]
     if stochastic:
         in_specs += [one_spec, one_spec]
@@ -635,10 +634,10 @@ def fused_update_pallas(
     if spec.needs_norms:
         in_specs += [one_spec]
         args += [tscale_blocks]
-    in_specs += [const_spec, const_spec]
+    in_specs += [table_spec, table_spec]
     args += [qm1, b1]
     if two:
-        in_specs += [const_spec, const_spec]
+        in_specs += [table_spec, table_spec]
         args += [qm2, b2]
     in_specs += [row_spec, row_spec, code1_spec, one_spec]
     args += [p, g, codes_m, absmax_m[:, None]]

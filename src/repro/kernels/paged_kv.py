@@ -24,8 +24,11 @@ Two data paths, both independent of the page *allocator* (host-side, in
     slot's page table are gathered and decoded to (B, L, KV, Dh) values.
     ``impl="pallas"`` is the TPU kernel: the page table rides scalar
     prefetch (``PrefetchScalarGridSpec``) so each grid step DMAs exactly
-    one physical page HBM->VMEM, and the codebook lookup is the SMEM
-    select chain every kernel in this package uses (common.decode).
+    one physical page HBM->VMEM, and the codebook lookup is the lane
+    gather every kernel in this package uses (common.decode).  Codes,
+    absmax and output keep the cache's own layout, so XLA copies nothing
+    around the kernel; one more lane gather moves each row's absmax from
+    its head's lane onto the row (``_row_scale``).
     ``impl="jnp"`` is the XLA oracle; parity is exercised in
     tests/test_serve_paged.py.
 """
@@ -142,14 +145,30 @@ def _gather_kernel(table_ref, codes_ref, absmax_ref, qmap_ref, out_ref,
                    *, bits: int):
     """One grid step = one (slot, logical page) cell: the physical page
     selected by the scalar-prefetched table is already in VMEM (index_map
-    DMA); unpack -> select-chain decode -> scale."""
+    DMA); unpack -> lane-gather decode -> scale."""
     del table_ref  # consumed by the index maps
     codes = codes_ref[0]                         # (page, KV, W) uint8
     if bits != 8:
         codes = unpack_codes(codes, bits)        # (page, KV, Dh)
     vals = common.decode(codes.astype(jnp.int32), qmap_ref,
                          n_levels=2 ** bits)
-    out_ref[0] = (vals * absmax_ref[0]).astype(out_ref.dtype)
+    out_ref[0] = (vals * _row_scale(absmax_ref[0], vals.shape)
+                  ).astype(out_ref.dtype)
+
+
+def _row_scale(absmax: jax.Array, shape) -> jax.Array:
+    """(page, KV) absmax -> (page, KV, Dh): row (p, k) holds absmax[p, k] in
+    every lane.  The KV heads sit on lanes, and a row needs its head on
+    sublanes: a lane gather of each position's (KV,) vector by the row's
+    own head index moves it there."""
+    page, kv, dh = shape
+    a = jnp.concatenate(
+        [absmax, jnp.zeros((page, common.LANES - kv), absmax.dtype)], axis=1)
+    a = jnp.broadcast_to(a[:, None, :], (page, kv, common.LANES))
+    a = a.reshape(page * kv, common.LANES)
+    head = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0) % kv
+    a = jnp.take_along_axis(a, head, axis=1, mode="promise_in_bounds")
+    return jnp.broadcast_to(a[:, :1], (page * kv, dh)).reshape(shape)
 
 
 def _gather_pallas(pages_codes, pages_absmax, page_table, *, bits, dtype,
@@ -168,11 +187,8 @@ def _gather_pallas(pages_codes, pages_absmax, page_table, *, bits, dtype,
         in_specs=[
             pl.BlockSpec((1, page, KV, W),
                          lambda b, p, t: (t[b, p], 0, 0, 0)),
-            # absmax carries a trailing unit dim so the block's last two
-            # dims (KV, 1) stay a legal 2-D tile broadcast over Dh.
-            pl.BlockSpec((1, page, KV, 1),
-                         lambda b, p, t: (t[b, p], 0, 0, 0)),
-            common.smem_spec(),
+            pl.BlockSpec((1, page, KV), lambda b, p, t: (t[b, p], 0, 0)),
+            common.table_spec(),
         ],
         out_specs=pl.BlockSpec((1, page, KV, Dh),
                                lambda b, p, t: (b, p, 0, 0)),
@@ -182,7 +198,7 @@ def _gather_pallas(pages_codes, pages_absmax, page_table, *, bits, dtype,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, P * page, KV, Dh), dtype),
         interpret=interpret,
-    )(table, pages_codes, pages_absmax[..., None], qmap)
+    )(table, pages_codes, pages_absmax, qmap)
 
 
 def _gather_jnp(pages_codes, pages_absmax, page_table, *, bits, dtype):

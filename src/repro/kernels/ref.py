@@ -9,7 +9,7 @@ All functions operate in the flat block domain: state tensors are
 optimizer update: it shares the 32-bit update math and norm finalization
 with ``fused_update.py`` (parity by construction) but keeps independent
 quantization mechanics (searchsorted + gather instead of the kernels'
-compare-sum + select-chain lookup).  It also implements the ablation modes
+lane-gather binary search and lookup).  It also implements the ablation modes
 the Pallas path does not serve: tensor-wise (single absmax) quantization.
 It is registered in ``ops.py`` as the ``impl="jnp"`` entry for every
 algorithm — the only surviving form of the old multi-pass jnp fallback.

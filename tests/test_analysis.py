@@ -236,6 +236,26 @@ def test_fused_update_tile_matches_blockspec_layout():
     assert tl.config["bits_r"] is None
 
 
+def test_budget_counts_codebook_lane_tables():
+    """Codebooks and boundaries are (2, 128) f32 VMEM blocks (the kernels'
+    ``common.table_spec``), each filling one (8, 128) f32 tile: adamw holds
+    four (two per state), lars two, quantize one (boundaries) and
+    dequantize one (codebook); the scalars vector stays in SMEM."""
+    from repro.kernels import common as kernels_common
+    from repro.kernels import fused_update
+
+    assert kernels_common.table_spec().block_shape == (2, 128)
+    table = kernel_budget.TABLE_BYTES
+    assert table == 8 * 128 * 4
+    scal = fused_update.N_SCALARS * 4
+    assert kernel_budget.fused_update_tile("adamw").invariant == \
+        scal + 4 * table
+    assert kernel_budget.fused_update_tile("lars").invariant == \
+        scal + 2 * table
+    assert kernel_budget.quantize_tile().invariant == table
+    assert kernel_budget.dequantize_tile().invariant == table
+
+
 def test_budget_audit_clean_and_oversized_detected():
     results = kernel_budget.audit()
     bad = [r for r in results if not r[1]]

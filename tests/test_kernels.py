@@ -4,9 +4,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import qmap
-from repro.kernels import ops, ref
+from repro.kernels import common, ops, ref
 
 QS = jnp.asarray(qmap.get_qmap("dynamic", True))
 QU = jnp.asarray(qmap.get_qmap("dynamic", False))
@@ -206,3 +207,74 @@ def test_quantize_other_codebooks():
         c_k, a_k = ops.quantize_blockwise(x, cb, impl="interpret")
         c_r, a_r = ref.quantize_ref(x, cb)
         np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_r))
+
+
+# ------------------------------------------- lookup exactness (common.py)
+# Every map the kernels quantize with, at 8 bits and at the k-bit widths.
+LOOKUP_MAPS = [(name, signed, bits)
+               for name, signed in [("dynamic", True), ("dynamic", False),
+                                    ("linear", True), ("quantile_normal", True)]
+               for bits in (8, 4, 5, 6)]
+
+
+def _lookup_inputs(cb: np.ndarray):
+    """Every boundary with its f32 neighbours on each side, every code
+    value, +-0, +-inf, NaN and values past +-1, as a (4, N) f32 tile."""
+    b = qmap.boundaries(cb)
+    vals = np.concatenate([
+        b, np.nextafter(b, np.float32(-np.inf)),
+        np.nextafter(b, np.float32(np.inf)), cb,
+        np.asarray([0.0, -0.0, np.inf, -np.inf, np.nan, 1.5, -1.5],
+                   np.float32)]).astype(np.float32)
+    n = -(-vals.size // (4 * 128)) * 128
+    x = np.full((4 * n,), np.nan, np.float32)
+    x[:vals.size] = vals
+    return x.reshape(4, n), b
+
+
+def _in_kernel(fn, x, table):
+    """Run ``fn(x, table_ref)`` inside an interpret-mode Pallas call with
+    the table as its VMEM lane-table operand."""
+    def kernel(x_ref, t_ref, o_ref):
+        o_ref[...] = fn(x_ref[...], t_ref)
+
+    out_dtype = jnp.int32 if x.dtype == jnp.float32 else jnp.float32
+    return pl.pallas_call(
+        kernel, grid=(1,),
+        in_specs=[pl.BlockSpec(x.shape, lambda i: (0, 0)),
+                  common.table_spec()],
+        out_specs=pl.BlockSpec(x.shape, lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, out_dtype),
+        interpret=True)(x, table)
+
+
+@pytest.mark.parametrize("impl", ["interpret", "jnp"])
+@pytest.mark.parametrize("name,signed,bits", LOOKUP_MAPS)
+def test_lookup_is_exact(name, signed, bits, impl):
+    """``common.encode`` is ``sum_j [x >= b_j]`` and ``common.decode`` is
+    ``qmap[code]``, bit for bit, at and beside every boundary, on every
+    code and on the special values — inside a kernel and outside."""
+    cb = qmap.get_qmap(name, signed, bits=bits)
+    levels = 1 << bits
+    x, b = _lookup_inputs(cb)
+    codes = (np.arange(x.size) % levels).astype(np.int32).reshape(x.shape)
+    bounds, table = common.padded_bounds(cb), common.padded_qmap(cb)
+
+    def enc(v, t):
+        return common.encode(v, t, levels)
+
+    def dec(c, t):
+        return common.decode(c, t, levels)
+
+    if impl == "interpret":
+        got_codes = _in_kernel(enc, jnp.asarray(x), bounds)
+        got_vals = _in_kernel(dec, jnp.asarray(codes), table)
+    else:
+        got_codes = enc(jnp.asarray(x), bounds)
+        got_vals = dec(jnp.asarray(codes), table)
+    # The sum in XLA too: it treats an f32 subnormal as the kernels do.
+    want_codes = jnp.sum(jnp.asarray(x)[..., None] >= jnp.asarray(b),
+                         axis=-1)
+    np.testing.assert_array_equal(np.asarray(got_codes), want_codes)
+    np.testing.assert_array_equal(np.asarray(got_vals).view(np.uint32),
+                                  cb[codes].view(np.uint32))

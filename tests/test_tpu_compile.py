@@ -86,7 +86,8 @@ def test_blockwise_quant_dequant_compile(compile_on_chip):
     ("adamw", 8, True), ("adam", 4, False), ("lamb", 8, False)])
 def test_fused_update_compile(compile_on_chip, algo, bits_m, stochastic):
     """adam/adamw at 8 bits and state_bits=(4, 8), and lamb with its norm
-    prologue: the fused update at one real weight's block count."""
+    prologue (a kernel of its own, which decodes both states): the fused
+    update at one real weight's block count."""
     qm, qr = _qmap(bits_m), _qmap(8, signed=False)
 
     def step(p, g, cm, am, cr, ar):
@@ -96,12 +97,14 @@ def test_fused_update_compile(compile_on_chip, algo, bits_m, stochastic):
                                 step=3.0, stochastic=stochastic,
                                 impl="pallas")
 
-    compile_on_chip(step, ((N_BLOCKS, BLOCK), jnp.float32),
-                    ((N_BLOCKS, BLOCK), jnp.float32),
-                    ((N_BLOCKS, BLOCK * bits_m // 8), jnp.uint8),
-                    ((N_BLOCKS,), jnp.float32),
-                    ((N_BLOCKS, BLOCK), jnp.uint8),
-                    ((N_BLOCKS,), jnp.float32))
+    text = compile_on_chip(step, ((N_BLOCKS, BLOCK), jnp.float32),
+                           ((N_BLOCKS, BLOCK), jnp.float32),
+                           ((N_BLOCKS, BLOCK * bits_m // 8), jnp.uint8),
+                           ((N_BLOCKS,), jnp.float32),
+                           ((N_BLOCKS, BLOCK), jnp.uint8),
+                           ((N_BLOCKS,), jnp.float32))
+    kernels = 2 if algo == "lamb" else 1
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
 
 
 def test_newton_schulz_compile(compile_on_chip):
@@ -124,3 +127,15 @@ def test_paged_kv_gather_compile(compile_on_chip):
                 c, a, t, bits=bits, dtype=jnp.bfloat16, impl="pallas"),
             ((512, page, kv, dh * bits // 8), jnp.uint8),
             ((512, page, kv), jnp.float32), ((8, 32), jnp.int32))
+
+
+@pytest.mark.parametrize("kv,dh", [(1, 256), (8, 128)])
+def test_paged_kv_gather_compile_head_layouts(compile_on_chip, kv, dh):
+    """The paged gather for other kv-head layouts (one head of 256, eight
+    of 128): head dims past one vreg row, and few heads per page."""
+    for bits in (8, 4):
+        compile_on_chip(
+            lambda c, a, t: paged_kv.gather_pages(
+                c, a, t, bits=bits, dtype=jnp.bfloat16, impl="pallas"),
+            ((64, 16, kv, dh * bits // 8), jnp.uint8),
+            ((64, 16, kv), jnp.float32), ((4, 8), jnp.int32))
